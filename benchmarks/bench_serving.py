@@ -10,7 +10,7 @@ policy behind the ``EngineBackend`` protocol changes.
 
     PYTHONPATH=src python -m benchmarks.bench_serving \
         --backends wgkv,dense [--smoke] [--arrival poisson:0.5] \
-        [--mesh 2x4] [--slo-tolerance 0.25] [--trace-out trace.json]
+        [--mesh 1x4] [--slo-tolerance 0.25] [--trace-out trace.json]
 
 Drivers replaying every trace (the scheduler tick is always the fused
 megabatch call — ONE jitted ragged device call per tick advancing every
@@ -63,7 +63,9 @@ traffic model the TTFT tail percentiles are meaningful under.
 
 With ``--mesh dxm`` every backend runs its jitted decode/extend SPMD over
 a ("data", "model") device mesh (serving/sharded.py); on a dev box use
-the debug recipe ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+the debug recipe ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
+(a v5e host has four chips: 1x4 or 2x2). ``main()`` places JAX's
+persistent compilation cache (repro.launch.compile_cache).
 
 Emits CSV rows for benchmarks.run and writes ``BENCH_serving.json``
 (``{"trace": ..., "backends": {name: metrics}, "ab": ratios-vs-dense}``)
@@ -93,6 +95,7 @@ import numpy as np
 from benchmarks.common import SEQ, trained_model
 from repro.core.selection import PAGE_SIZE
 from repro.data.synthetic import needle_task
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.backend import BACKEND_NAMES, make_backend
 from repro.serving.obs import (Tracer, validate_chrome_trace,
                                write_chrome_trace)
@@ -694,9 +697,9 @@ def main() -> None:
                     help="arrival process: burst | poisson:<rate> "
                          "(mean arrivals per scheduler tick)")
     ap.add_argument("--mesh", default=None, metavar="DxM",
-                    help="data x model mesh for SPMD decode, e.g. 2x4 "
+                    help="data x model mesh for SPMD decode, e.g. 1x4 "
                          "(debug: XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=8)")
+                         "--xla_force_host_platform_device_count=4)")
     ap.add_argument("--slo-tolerance", type=float, default=None,
                     metavar="FRAC",
                     help="fail (exit 1) when a backend's p99 TTFT exceeds "
@@ -708,6 +711,7 @@ def main() -> None:
                          "Chrome-trace JSONs, one per backend "
                          "(trace.json -> trace.wgkv.json, ...)")
     args = ap.parse_args()
+    enable_compile_cache()
     # snapshot the committed history BEFORE run() overwrites it
     prev_record = None
     if args.slo_tolerance is not None and os.path.exists(JSON_PATH):

@@ -38,8 +38,7 @@ cfg = get_reduced_config("phi4-mini-3.8b").replace(
                     global_budget_frac=0.4, sink=4))
 params = T.init_model(jax.random.PRNGKey(0), cfg)
 
-eng = Engine(params, cfg, slots=3, capacity=512, pool_pages=8192,
-             temperature=0.0)
+eng = Engine(params, cfg, slots=3, capacity=512, temperature=0.0)
 orch = Orchestrator(eng, sched=SchedulerConfig(chunk_tokens=64))
 
 key = jax.random.PRNGKey(7)

@@ -25,7 +25,7 @@ def _kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref):
     o_ref[0] = jax.nn.sigmoid(y[..., 0]).astype(o_ref.dtype)
 
 
-def gate_mlp(x, w1, b1, w2, b2, *, bs: int = 256, interpret: bool = True):
+def gate_mlp(x, w1, b1, w2, b2, *, bs: int = 256, interpret: bool):
     """x: [H, S, F]; w1: [H, F, M]; b1: [H, M]; w2: [H, M, 1]; b2: [H, 1]
     -> g [H, S] float32."""
     h, s, f = x.shape

@@ -66,7 +66,7 @@ def _kernel(q_ref, k_ref, v_ref, g_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def gated_flash(q, k, v, g, *, w_local: int, eps: float = 1e-6,
-                bq: int = 128, bk: int = 128, interpret: bool = True):
+                bq: int = 128, bk: int = 128, interpret: bool):
     """q: [N, S, hd]; k, v: [N, S, hd]; g: [N, S] -> [N, S, hd]."""
     n, s, hd = q.shape
     bq = min(bq, s)

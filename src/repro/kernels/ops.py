@@ -1,9 +1,10 @@
 """jit'd wrappers around the Pallas kernels, handling model-level shapes
 (GQA head folding, global-token gathering, dual-cache paging).
 
-``interpret`` defaults to True off-TPU so the same call sites work in this
-CPU container (kernel bodies execute under the Pallas interpreter) and
-compile to real Mosaic kernels on TPU.
+Every wrapper takes its ``interpret`` flag from :func:`interpret_mode`:
+kernel bodies run under the Pallas interpreter on the CPU (tests) and
+compile to Mosaic kernels on the TPU. Any other backend is an error, so a
+run that lost its chip never carries on interpreted in silence.
 """
 from __future__ import annotations
 
@@ -19,8 +20,16 @@ from repro.kernels.rglru_scan import rglru_scan_pallas
 from repro.kernels.vertical_slash import vertical_slash
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """``interpret`` for the Pallas kernels on the default backend: True on
+    the CPU, False on the TPU; any other backend raises."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel mode for backend {backend!r} "
+                       "(expected 'cpu' or 'tpu')")
 
 
 def _fold_gqa(q, k, v):
@@ -43,7 +52,7 @@ def gated_flash_attention(q, k, v, g, *, w_local: int, bq: int = 128,
     qf, kf, vf, (b, hq, s, hd, grp) = _fold_gqa(q, k, v)
     gf = jnp.repeat(g.reshape(-1, s), grp, axis=0)
     of = gated_flash(qf, kf, vf, gf, w_local=w_local, bq=bq, bk=bk,
-                     interpret=_interpret_default())
+                     interpret=interpret_mode())
     return of.reshape(b, hq, s, hd)
 
 
@@ -58,7 +67,7 @@ def vertical_slash_attention(q, k, v, kg, vg, gpos, *, w_local: int,
     vgf = jnp.repeat(vg.reshape(-1, c, hd), grp, axis=0)
     gpf = jnp.repeat(gpos.reshape(-1, c), grp, axis=0)
     of = vertical_slash(qf, kf, vf, kgf, vgf, gpf, w_local=w_local, bc=bc,
-                        interpret=_interpret_default())
+                        interpret=interpret_mode())
     return of.reshape(b, hq, s, hd)
 
 
@@ -74,7 +83,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths):
     tf = jnp.repeat(page_table.reshape(b * hkv, mp), g, axis=0)
     lf = jnp.repeat(lengths.reshape(b * hkv), g, axis=0)
     of = paged_decode(qf, k_pool, v_pool, tf, lf,
-                      interpret=_interpret_default())
+                      interpret=interpret_mode())
     return of.reshape(b, hq, hd)
 
 
@@ -82,7 +91,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, lengths):
 def rglru_linear_scan(a, b, *, bt: int = 128, bd: int = 128):
     """[B,S,D] linear recurrence via the blocked Pallas scan."""
     return rglru_scan_pallas(a, b, bt=bt, bd=bd,
-                             interpret=_interpret_default())
+                             interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("bs",))
@@ -96,5 +105,5 @@ def write_gate(x, w1, b1, w2, b2, *, bs: int = 256):
     w2f = jnp.tile(w2, (b, 1, 1))
     b2f = jnp.tile(b2, (b, 1))
     g = gate_mlp(xf, w1f, b1f, w2f, b2f, bs=bs,
-                 interpret=_interpret_default())
+                 interpret=interpret_mode())
     return g.reshape(b, h, s)

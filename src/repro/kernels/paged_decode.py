@@ -57,7 +57,7 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_decode(q, k_pool, v_pool, page_table, lengths, *,
-                 interpret: bool = True):
+                 interpret: bool):
     """q: [N, hd]; k_pool/v_pool: [P, page, hd]; page_table: [N, max_pages]
     int32 physical page ids; lengths: [N] valid tokens. Returns [N, hd]."""
     n, hd = q.shape
@@ -131,7 +131,7 @@ def _kernel_selected(table_ref, len_ref, sel_ref, nsel_ref,
 
 
 def paged_decode_selected(q, k_pool, v_pool, page_table, lengths,
-                          sel_ids, n_sel, *, interpret: bool = True):
+                          sel_ids, n_sel, *, interpret: bool):
     """Quest-selected paged decode: attend over only the top-K pages.
 
     Same layout as :func:`paged_decode` plus ``sel_ids`` [N, K] int32

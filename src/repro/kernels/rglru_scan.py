@@ -37,7 +37,7 @@ def _kernel(a_ref, b_ref, o_ref, h_ref, *, bt: int):
 
 
 def rglru_scan_pallas(a, b, *, bt: int = 128, bd: int = 128,
-                      interpret: bool = True):
+                      interpret: bool):
     """a, b: [B, S, D] float32 -> h [B, S, D]."""
     bsz, s, d = a.shape
     bt = min(bt, s)

@@ -86,7 +86,7 @@ def _kernel(q_ref, kp_ref, vp_ref, kc_ref, vc_ref, kg_ref, vg_ref, gpos_ref,
 
 
 def vertical_slash(q, k, v, kg, vg, gpos, *, w_local: int, bc: int = 128,
-                   interpret: bool = True):
+                   interpret: bool):
     """q, k, v: [N, S, hd]; kg, vg: [N, C, hd]; gpos: [N, C] int32.
     S % w_local == 0 and C % bc == 0 required. Returns [N, S, hd]."""
     n, s, hd = q.shape

@@ -99,10 +99,10 @@ def run_dryrun(arch: str, shape_name: str, *, multi_pod: bool = False,
         "lower_s": round(t_lower, 1),
         "compile_s": round(t_compile, 1),
         "memory": {
-            "argument_bytes": getattr(mem, "argument_size_in_bytes", None),
-            "output_bytes": getattr(mem, "output_size_in_bytes", None),
-            "temp_bytes": getattr(mem, "temp_size_in_bytes", None),
-            "peak_bytes": getattr(mem, "peak_memory_in_bytes", None),
+            "argument_bytes": mem.argument_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+            "peak_bytes": mem.peak_memory_in_bytes,
         },
         "cost": {
             "flops": cost.get("flops"),

@@ -16,8 +16,16 @@ Quest / SnapKV composition).
     PYTHONPATH=src python -m repro.launch.serve \
         --arch qwen3-0.6b --reduced --trace-out trace.json \
         --metrics-interval 5                               # observability
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
-        python -m repro.launch.serve --arch qwen3-0.6b --reduced --mesh 2x4
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b \
+        --reduced --mesh 1x4
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b  # TPU
+
+``--reduced`` is the CPU recipe: a cut-down config in float32 with the
+Pallas kernels interpreted. Without it the published config serves in its
+own dtype (bfloat16 for qwen3-0.6b) with compiled kernels. JAX's
+persistent compilation cache goes to ``JAX_COMPILATION_CACHE_DIR`` when
+set, else to ``<repo>/.jax_cache`` (repro.launch.compile_cache).
 """
 from __future__ import annotations
 
@@ -29,6 +37,7 @@ import jax
 
 from repro.configs import ARCH_NAMES, get_config, get_reduced_config
 from repro.core import admission as A
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import inference as I
 from repro.models import transformer as T
 from repro.serving.backend import BACKEND_NAMES, make_backend
@@ -79,8 +88,8 @@ def main() -> None:
                     help="queue backpressure bound (default unbounded)")
     ap.add_argument("--mesh", default=None, metavar="DxM",
                     help="run decode/extend SPMD over a data x model mesh, "
-                         "e.g. 2x4 (debug recipe: XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=8)")
+                         "e.g. 1x4 (debug recipe: XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=4)")
     ap.add_argument("--quest-pages", type=int, default=None)
     ap.add_argument("--evict-budget", type=int, default=None)
     ap.add_argument("--temperature", type=float, default=0.0)
@@ -117,8 +126,11 @@ def main() -> None:
         ap.error("--metrics-interval must be > 0")
     if args.prefix_cache_mb < 1:
         ap.error("--prefix-cache-mb must be >= 1")
-    cfg = get_reduced_config(args.arch) if args.reduced else get_config(args.arch)
-    cfg = cfg.replace(dtype="float32")
+    enable_compile_cache()
+    if args.reduced:
+        cfg = get_reduced_config(args.arch).replace(dtype="float32")
+    else:
+        cfg = get_config(args.arch)
     if not cfg.has_attention_cache:
         raise SystemExit(f"{args.arch} has no KV cache; engine serves "
                          "attention archs (SSM decode via examples/)")
@@ -226,7 +238,8 @@ def main() -> None:
             session.tick()
         session.orchestrator.drain()  # settle the mirror before verifying
         dev = eng.verify_paged() if any(eng.live) else 0.0
-        print(f"\npaged-vs-logical max deviation (live request): {dev:.2e}")
+        print(f"\npaged-vs-logical max deviation (live request): {dev:.2e} "
+              f"(limit {eng.PAGED_ATOL:.0e})")
         session.run()
     session.close()
     if args.trace_out and tracer is not None:

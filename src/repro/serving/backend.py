@@ -191,6 +191,9 @@ class FusedStep(InflightStep):
     # this step ran the gathered top-K page-selection variant (decode-only
     # dispatch on a selection-configured backend)
     selection: bool = False
+    # [slots, V] device logits each row sampled ``tokens`` from (its
+    # last real position this step); a reference, never pulled by collect
+    logits: Any = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -276,7 +279,7 @@ def make_backend(name: str, params, cfg, **kw) -> EngineBackend:
     ``opts.selection_policy``; dual-cache backends only), and ``mesh``
     (a ``jax.sharding.Mesh`` with ("data", "model") axes — decode/extend
     run SPMD over it; see serving/sharded.py and
-    ``repro.serving.sharded.build_mesh``). WG-KV family: ``pool_pages``,
+    ``repro.serving.sharded.build_mesh``). WG-KV family:
     ``mirror_paged``. Static admission: ``sink``, ``retrieval_heads`` /
     ``retrieval_ratio`` (duo).
     """

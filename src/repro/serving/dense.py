@@ -46,7 +46,7 @@ class DenseEngine(Engine):
                  eos: Optional[int] = None, temperature: float = 0.0,
                  seed: int = 0, mesh=None, **_paged_kw):
         # dense caches are contiguous [B, H, capacity, hd] buffers; the
-        # paged mirror (and pool_pages/mirror_paged kwargs) do not apply
+        # paged mirror (and the mirror_paged kwarg) does not apply
         if opts is not None and opts.selection_policy is not None:
             raise ValueError(
                 "selection_policy requires the paged dual cache; the dense "

@@ -101,7 +101,7 @@ class Engine(ShardedDecodeMixin):
 
     def __init__(self, params, cfg: ModelConfig, *, slots: int = 4,
                  capacity: int = 4096, opts: Optional[I.DecodeOptions] = None,
-                 pool_pages: int = 4096, eos: Optional[int] = None,
+                 eos: Optional[int] = None,
                  temperature: float = 0.0, seed: int = 0,
                  mirror_paged: bool = True, mesh: Optional[Mesh] = None):
         assert cfg.has_attention_cache, "engine serves KV-cache archs"
@@ -125,8 +125,6 @@ class Engine(ShardedDecodeMixin):
         # still belongs to the request a step was dispatched for
         self._slot_gen: List[int] = [0] * slots
         self.mirror = mirror_paged
-        if mirror_paged:
-            self.pool = paged.PagedKVPool(pool_pages, cfg.head_dim)
         # decode-time page selection: the engine's base opts run the full
         # path (prefill chunks and mixed ticks must see every admitted
         # token); the policy compiles into a SECOND fused-step variant
@@ -135,6 +133,13 @@ class Engine(ShardedDecodeMixin):
         self._sel_k = I.parse_selection_policy(self.selection)  # validates
         if self.selection is not None:
             self.opts = dataclasses.replace(self.opts, selection_policy=None)
+        if mirror_paged:
+            # every slot row at its largest, plus the null page; each
+            # prefix-store entry holding pool streams reserves one more row
+            self._row_pages = self._row_pool_pages()
+            self._pool_entries: set = set()
+            self.pool = paged.PagedKVPool(slots * self._row_pages + 1,
+                                          cfg.head_dim)
         self.params = self._sharding_setup(params, mesh)
         self._extend_batch = self._make_extend_batch()
         self._fused = self._make_fused_step()
@@ -259,6 +264,23 @@ class Engine(ShardedDecodeMixin):
             if isinstance(node, DualCache):
                 return node.gk
         return None
+
+    def _row_pool_pages(self) -> int:
+        """Most pool pages one cache row's streams can hold, read off the
+        engine's own cache shapes: per (dual-cache layer, KV head), the
+        global budget plus the ring, each in whole pages."""
+        tree = jax.eval_shape(self._build_empty_caches)
+        pages = 0
+        for node in tree["blocks"].values():
+            if isinstance(node, dict) and "self" in node:
+                node = node["self"]
+            if isinstance(node, DualCache):
+                *lead, heads, cap, _ = node.gk.shape
+                ring = node.lk.shape[-2]
+                layers = lead[0] if len(lead) == 2 else 1
+                pages += layers * heads * (-(-cap // paged.PAGE_SIZE)
+                                           + -(-ring // paged.PAGE_SIZE))
+        return pages
 
     def _kv_tokens_device(self, caches) -> jax.Array:
         """[B] resident KV token count per row, computed from device
@@ -536,10 +558,10 @@ class Engine(ShardedDecodeMixin):
             if use_sel:
                 with self.tracer.span("selection", k=self._sel_k,
                                       rows=len(decode_rows)):
-                    _logits, self.caches, st = self._fused_sel(
+                    logits, self.caches, st = self._fused_sel(
                         self.params, feed, before)
             else:
-                _logits, self.caches, st = self._fused(
+                logits, self.caches, st = self._fused(
                     self.params, feed, before)
         sampled = st["sampled"]
         # host bookkeeping at dispatch (teacher-forced, deterministic):
@@ -573,7 +595,8 @@ class Engine(ShardedDecodeMixin):
             live=tuple(self.live), gen=tuple(self._slot_gen),
             tasks=tuple(tasks), takes=tuple(takes), fulls=tuple(fulls),
             finishing=tuple(finishing), decode_rows=decode_rows,
-            had_prefill=bool(tasks), t_dispatch=t0, selection=use_sel)
+            had_prefill=bool(tasks), t_dispatch=t0, selection=use_sel,
+            logits=logits)
 
     def _pre_fused_dispatch(self, prefill: List[Tuple[int, int]],
                             decode_rows: Tuple[int, ...]) -> None:
@@ -744,6 +767,11 @@ class Engine(ShardedDecodeMixin):
         window, exactly like insert's mirror."""
         from repro.serving.prefix_cache import CachedPrefix
         caches = extract_slot_caches(step.after, slot)
+        if self.mirror:
+            # the entry's streams hold at most one row's pages
+            self._pool_entries.add(key)
+            self.pool.reserve((self.slots + len(self._pool_entries))
+                              * self._row_pages + 1)
         meta: Dict[Tuple, Dict] = {}
         stream_keys: List[Tuple] = []
         kv_tokens = 0
@@ -784,6 +812,7 @@ class Engine(ShardedDecodeMixin):
         """Free an evicted store entry's pool streams. Pages a live slot
         still shares survive via their per-page refcounts."""
         if self.mirror:
+            self._pool_entries.discard(entry.key)
             for skey in entry.stream_keys:
                 self.pool.free_stream(skey)
 
@@ -1008,11 +1037,21 @@ class Engine(ShardedDecodeMixin):
                 break
 
     # ------------------------------------------------------------------
-    def verify_paged(self, layer_repeat: int = 0, block: int = 0,
-                     atol: float = 2e-3) -> float:
+    # largest paged-vs-logical deviation verify_paged accepts: the pool is
+    # float32, so it holds the logical cache's values exactly and only the
+    # kernel's attention arithmetic separates the two (full float32 when
+    # interpreted; bfloat16 passes at the TPU's default matmul precision)
+    PAGED_ATOL = 2e-3
+
+    def verify_paged(self, layer_repeat: int = 0, block: int = 0) -> float:
         """Recompute one layer's decode attention for all live slots from
         the PHYSICAL pool via the paged_decode kernel and compare with the
-        logical dual-cache contents. Returns max abs deviation."""
+        logical dual-cache contents. Returns max abs deviation (healthy:
+        below :attr:`PAGED_ATOL`). The kernel runs compiled on the TPU and
+        interpreted on the CPU (:func:`repro.kernels.ops.interpret_mode`)."""
+        from repro.kernels.ops import interpret_mode
+        from repro.kernels.paged_decode import paged_decode
+
         assert self.mirror and self.caches is not None
         live = [s for s in range(self.slots) if self.live[s]]
         if not live:
@@ -1020,7 +1059,9 @@ class Engine(ShardedDecodeMixin):
         node = self.caches["blocks"][f"b{block}"]
         if isinstance(node, dict):
             node = node["self"]
-        dc: DualCache = jax.tree.map(lambda x: x[layer_repeat], node)
+        # one host pull of the layer (every slot row, this repeat only)
+        dc: DualCache = jax.device_get(
+            jax.tree.map(lambda x: x[layer_repeat], node))
         worst = 0.0
         for slot in live:
             n_local = min(int(dc.t[slot]), dc.w_local)
@@ -1045,8 +1086,8 @@ class Engine(ShardedDecodeMixin):
         if int(lens.max()) > 0:
             hd = self.cfg.head_dim
             q = jnp.ones((len(keys), hd), jnp.float32) / hd
-            from repro.kernels.paged_decode import paged_decode
-            out = paged_decode(q, kp, vp, tbl, lens)
+            out = np.asarray(paged_decode(q, kp, vp, tbl, lens,
+                                          interpret=interpret_mode()))
             # oracle from logical cache
             i = 0
             for s in live:
@@ -1060,6 +1101,6 @@ class Engine(ShardedDecodeMixin):
                         w /= w.sum()
                         oracle = w @ vv
                         worst = max(worst, float(
-                            np.abs(np.asarray(out[i]) - oracle).max()))
+                            np.abs(out[i] - oracle).max()))
                     i += 1
         return worst

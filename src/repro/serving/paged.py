@@ -69,6 +69,18 @@ class PagedKVPool:
         else:
             self._refs[page] = n - 1
 
+    def reserve(self, num_pages: int) -> None:
+        """Grow the pool to at least ``num_pages`` pages (never shrinks)."""
+        extra = num_pages - self.num_pages
+        if extra <= 0:
+            return
+        pad = np.zeros((extra, PAGE_SIZE, self.head_dim), np.float32)
+        self.k = np.concatenate([self.k, pad])
+        self.v = np.concatenate([self.v, pad])
+        # the free list pops from its end: new pages go to the front
+        self._free[:0] = range(num_pages - 1, self.num_pages - 1, -1)
+        self.num_pages = num_pages
+
     def refcount(self, page: int) -> int:
         return self._refs.get(page, 0)
 

@@ -28,11 +28,12 @@ mesh without the orchestrator or scheduler changing at all:
 Unmeshed (``mesh=None``) every helper degrades to the exact pre-sharding
 behavior: plain ``jax.jit`` and host-side splices.
 
-Debug recipe (no accelerator needed)::
+Debug recipe (no accelerator needed; a v5e host has four chips, so the
+recipes stay within 1x4 / 2x2)::
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python -m repro.launch.serve \
-        --arch qwen3-0.6b --reduced --mesh 2x4 --requests 4
+        --arch qwen3-0.6b --reduced --mesh 1x4 --requests 4
 """
 from __future__ import annotations
 
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_auto_mesh
 from repro.launch.specs import (alloc_batched_caches, extract_slot_caches,
                                 splice_caches)
 from repro.models import inference as I
@@ -54,12 +56,12 @@ from repro.sharding import rules
 # mesh construction from a CLI "dxm" spec
 # ==========================================================================
 def parse_mesh_shape(spec: str) -> Tuple[int, int]:
-    """``"2x4"`` -> ``(2, 4)`` (data ways, model ways)."""
+    """``"1x4"`` -> ``(1, 4)`` (data ways, model ways)."""
     try:
         d, m = spec.lower().split("x")
         shape = (int(d), int(m))
     except ValueError:
-        raise ValueError(f"mesh spec must look like '2x4' (data x model), "
+        raise ValueError(f"mesh spec must look like '1x4' (data x model), "
                          f"got {spec!r}") from None
     if shape[0] < 1 or shape[1] < 1:
         raise ValueError(f"mesh axes must be >= 1, got {spec!r}")
@@ -84,7 +86,7 @@ def build_mesh(spec: Optional[str]) -> Optional[Mesh]:
             "mesh on host devices set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need} before jax "
             "imports")
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_auto_mesh(shape, ("data", "model"))
 
 
 def _struct_key(tree: Any) -> Tuple:

@@ -14,8 +14,8 @@ def pytest_configure(config):
                    "serving A/B tests (pytest -m backends)")
     config.addinivalue_line(
         "markers", "sharded: mesh-sharded serving tests; in-process variants "
-                   "need >= 8 devices (CI runs the suite under XLA_FLAGS="
-                   "--xla_force_host_platform_device_count=8), subprocess "
+                   "need >= 4 devices (CI runs the suite under XLA_FLAGS="
+                   "--xla_force_host_platform_device_count=4), subprocess "
                    "variants set the flag themselves")
 
 

@@ -8,7 +8,7 @@ the full path — greedy streams must be BYTE-identical to selection off
 complete streams while touching fewer pages (``selected_pages``
 counter), and the incremental ``pkmin``/``pkmax`` page metadata the dual
 cache maintains in-jit must equal a from-scratch ``build_page_meta``
-rebuild after prefill + decode + slot-churn. The 2x4-mesh variant of the
+rebuild after prefill + decode + slot-churn. The 2x2-mesh variant of the
 stream parity lives in test_sharded_serving.py; the kernel-level sweep
 in test_kernels.py.
 """

@@ -55,11 +55,12 @@ def test_parse_ignores_async_done():
 
 SUBPROC = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json, jax
 from repro.configs import get_config, get_shape
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.steps import make_bundle
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh((2, 2))
 out = {}
 for arch, shp, wg in [("qwen3-0.6b", "train_4k", True),
                       ("qwen3-0.6b", "decode_32k", True),
@@ -71,14 +72,7 @@ for arch, shp, wg in [("qwen3-0.6b", "train_4k", True),
         compiled = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                            donate_argnums=bundle.donate_argnums
                            ).lower(*bundle.args).compile()
-    mem = compiled.memory_analysis()
-    # peak_memory_in_bytes disappeared from newer jaxlib CompiledMemoryStats;
-    # fall back to the arg+temp+output sum (same fields dryrun.py records)
-    peak = getattr(mem, "peak_memory_in_bytes", None)
-    if peak is None:
-        peak = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-                + mem.output_size_in_bytes)
-    out[f"{arch}/{shp}"] = peak
+    out[f"{arch}/{shp}"] = compiled.memory_analysis().peak_memory_in_bytes
 print(json.dumps(out))
 """
 

@@ -29,7 +29,7 @@ def test_gated_flash_sweep(n, s, hd, w, bq, bk, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v = (_rand(ks[i], (n, s, hd), dtype) for i in range(3))
     g = jax.nn.sigmoid(jax.random.normal(ks[3], (n, s))).astype(jnp.float32)
-    out = gated_flash(q, k, v, g, w_local=w, bq=bq, bk=bk)
+    out = gated_flash(q, k, v, g, w_local=w, bq=bq, bk=bk, interpret=True)
     r = ref.gated_flash_ref(q.astype(jnp.float32), k.astype(jnp.float32),
                             v.astype(jnp.float32), g, w_local=w)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(r),
@@ -53,7 +53,8 @@ def test_vertical_slash_sweep(n, s, hd, w, c, bc, dtype):
     safe = jnp.minimum(gpos, s - 1)
     kg = jnp.where((gpos < s)[..., None], k[bi, safe], 0)
     vg = jnp.where((gpos < s)[..., None], v[bi, safe], 0)
-    out = vertical_slash(q, k, v, kg, vg, gpos, w_local=w, bc=bc)
+    out = vertical_slash(q, k, v, kg, vg, gpos, w_local=w, bc=bc,
+                         interpret=True)
     r = ref.vertical_slash_ref(*(x.astype(jnp.float32)
                                  for x in (q, k, v, kg, vg)), gpos, w_local=w)
     assert not bool(jnp.isnan(out.astype(jnp.float32)).any())
@@ -72,7 +73,7 @@ def test_paged_decode_sweep(n, hd, page, ptotal, mp, dtype):
     vp = _rand(ks[2], (ptotal, page, hd), dtype)
     tbl = jax.random.randint(ks[3], (n, mp), 0, ptotal)
     lens = jax.random.randint(ks[4], (n,), 1, mp * page)
-    out = paged_decode(q, kp, vp, tbl, lens)
+    out = paged_decode(q, kp, vp, tbl, lens, interpret=True)
     r = ref.paged_decode_ref(*(x.astype(jnp.float32) for x in (q, kp, vp)),
                              tbl, lens)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(r),
@@ -95,7 +96,8 @@ def test_paged_decode_selected_sweep(n, hd, page, ptotal, mp, kp, dtype):
     perm = jax.random.uniform(ks[5], (n, mp)).argsort(axis=-1)[:, :kp]
     sel = jnp.sort(perm, axis=-1).astype(jnp.int32)
     nsel = jax.random.randint(ks[6], (n,), 1, kp + 1)
-    out = paged_decode_selected(q, kpool, vpool, tbl, lens, sel, nsel)
+    out = paged_decode_selected(q, kpool, vpool, tbl, lens, sel, nsel,
+                                interpret=True)
     r = ref.paged_decode_selected_ref(
         *(x.astype(jnp.float32) for x in (q, kpool, vpool)),
         tbl, lens, sel, nsel)
@@ -117,8 +119,9 @@ def test_paged_decode_selected_all_pages_identity():
     lens = jax.random.randint(ks[4], (n,), 1, mp * page)
     sel = jnp.broadcast_to(jnp.arange(mp, dtype=jnp.int32)[None], (n, mp))
     nsel = jnp.full((n,), mp, jnp.int32)
-    a = paged_decode(q, kpool, vpool, tbl, lens)
-    b = paged_decode_selected(q, kpool, vpool, tbl, lens, sel, nsel)
+    a = paged_decode(q, kpool, vpool, tbl, lens, interpret=True)
+    b = paged_decode_selected(q, kpool, vpool, tbl, lens, sel, nsel,
+                              interpret=True)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -129,7 +132,7 @@ def test_rglru_scan_sweep(b, s, d, bt, bd):
     ks = jax.random.split(jax.random.PRNGKey(3), 2)
     a = jax.nn.sigmoid(jax.random.normal(ks[0], (b, s, d)))
     bb = jax.random.normal(ks[1], (b, s, d))
-    out = rglru_scan_pallas(a, bb, bt=bt, bd=bd)
+    out = rglru_scan_pallas(a, bb, bt=bt, bd=bd, interpret=True)
     r = ref.rglru_scan_ref(a, bb)
     np.testing.assert_allclose(np.asarray(out), np.asarray(r), atol=1e-5)
 
@@ -144,7 +147,7 @@ def test_gate_mlp_sweep(h, s, f, m, bs):
     b1 = jax.random.normal(ks[2], (h, m)) * 0.1
     w2 = jax.random.normal(ks[3], (h, m, 1)) * 0.1
     b2 = jnp.zeros((h, 1))
-    out = gate_mlp(x, w1, b1, w2, b2, bs=bs)
+    out = gate_mlp(x, w1, b1, w2, b2, bs=bs, interpret=True)
     r = ref.gate_mlp_ref(x, w1, b1, w2, b2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(r), atol=1e-5)
     assert ((np.asarray(out) > 0) & (np.asarray(out) < 1)).all()

@@ -184,7 +184,7 @@ def test_orchestrator_with_composition(served):
 # ==========================================================================
 def test_pool_reclaimed_after_completion(served):
     cfg, params = served
-    eng = Engine(params, cfg, slots=2, capacity=128, pool_pages=4096)
+    eng = Engine(params, cfg, slots=2, capacity=128)
     orch = Orchestrator(eng, sched=SchedulerConfig(chunk_tokens=16))
     for i in range(4):  # more requests than slots -> slot churn
         orch.submit(list(range(10 + i, 58 + i)), max_new=4)

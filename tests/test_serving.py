@@ -47,9 +47,45 @@ def test_pool_fragmentation_metric():
     assert pool.utilization() == pytest.approx(1 / 16)
 
 
+def test_pool_reserve_grows_free_list():
+    pool = paged.PagedKVPool(3, head_dim=4)
+    pool.append((0,), np.ones(4), np.ones(4))
+    pool.reserve(5)
+    pool.reserve(4)                              # never shrinks
+    assert pool.num_pages == 5 and pool.k.shape[0] == 5
+    for _ in range(3 * paged.PAGE_SIZE):         # 3 more pages now fit
+        pool.append((1,), np.zeros(4), np.zeros(4))
+    assert pool.pages_in_use == 4
+    assert (pool.gather((0,))[0] == 1).all()
+    with pytest.raises(paged.PoolExhausted):
+        for _ in range(paged.PAGE_SIZE + 1):
+            pool.append((1,), np.zeros(4), np.zeros(4))
+
+
+def test_pool_sized_from_cache_shapes(served):
+    """slots x attention layers x KV heads x (global + ring pages) + the
+    null page: a full slot fits however much the gates admit."""
+    cfg, params = served
+    eng = Engine(params, cfg, slots=3, capacity=128)
+    g_pages = -(-cfg.wgkv.global_budget(128) // paged.PAGE_SIZE)
+    r_pages = -(-cfg.wgkv.w_local // paged.PAGE_SIZE)
+    row = cfg.n_layers * cfg.n_kv_heads * (g_pages + r_pages)
+    assert eng.pool.num_pages == 3 * row + 1
+
+
+def test_kernel_interpret_mode(monkeypatch):
+    from repro.kernels import ops
+    assert ops.interpret_mode() is True          # tests run on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.interpret_mode()
+
+
 def test_engine_end_to_end(served):
     cfg, params = served
-    eng = Engine(params, cfg, slots=2, capacity=128, pool_pages=4096)
+    eng = Engine(params, cfg, slots=2, capacity=128)
     rids = [eng.add_request(list(range(10 + i, 60 + i)), max_new=6)
             for i in range(3)]
     eng.run(max_steps=40)
@@ -62,7 +98,7 @@ def test_engine_paged_mirror_exact(served):
     """Physical pool bytes == logical dual cache, and the paged_decode
     kernel over the pool matches an oracle computed from the logical view."""
     cfg, params = served
-    eng = Engine(params, cfg, slots=2, capacity=128, pool_pages=4096)
+    eng = Engine(params, cfg, slots=2, capacity=128)
     eng.add_request(list(range(5, 55)), max_new=30)
     eng.add_request(list(range(100, 170)), max_new=30)
     for _ in range(10):

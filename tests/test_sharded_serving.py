@@ -4,15 +4,15 @@ Three layers of coverage:
 
   * spec-level: ``cache_shardings`` on DualCache trees — odd KV-head
     counts (phi3 10 KV heads, smollm 5) must fall back to replication on
-    "model" under the (2,4) debug mesh, ``seq_shard=True`` must put the
+    "model" under a (2,4) data x model axis map, ``seq_shard=True`` must put the
     global token axis on "data", and ``param_shardings`` must never split
     ``head_dim`` across "model" (whole-head column parallelism only).
-    These run on a single device via AbstractMesh.
+    These need no devices: the rules run on an AbstractMesh.
   * end-to-end parity (subprocess, sets its own XLA_FLAGS): greedy
-    tokens from the wgkv and dense backends under a (2,4) host-device
-    mesh must exactly match the unsharded backends on the same arrival
-    trace.
-  * in-process mesh tests (skipped unless >= 8 devices; CI provides
+    tokens from the wgkv and dense backends under a (2,2) host-device
+    mesh (a v5e host has four chips) must exactly match the unsharded
+    backends on the same arrival trace.
+  * in-process mesh tests (skipped unless >= 4 devices; CI provides
     them): sharded capabilities/memory_snapshot surface.
 """
 import json
@@ -30,7 +30,10 @@ from repro.sharding import rules
 
 pytestmark = pytest.mark.sharded
 
-MESH_SHAPE = (2, 4)
+# axis map of the spec-level tests (an AbstractMesh: no devices needed)
+SPEC_MESH_SHAPE = (2, 4)
+# device mesh of the parity/in-process tests: four devices, like a v5e host
+MESH_SHAPE = (2, 2)
 N_DEVICES = MESH_SHAPE[0] * MESH_SHAPE[1]
 
 needs_mesh = pytest.mark.skipif(
@@ -40,14 +43,9 @@ needs_mesh = pytest.mark.skipif(
 
 
 def spec_mesh():
-    """(2,4) data x model mesh for SPEC computation only: the real debug
-    mesh when enough devices exist, else an AbstractMesh with the same
-    axis map (rules.py only reads axis_names / shape)."""
-    if len(jax.devices()) >= N_DEVICES:
-        from repro.launch.mesh import make_debug_mesh
-        return make_debug_mesh(MESH_SHAPE)
-    return jax.sharding.AbstractMesh(
-        (("data", MESH_SHAPE[0]), ("model", MESH_SHAPE[1])))
+    """(2,4) data x model axis map for SPEC computation only (rules.py
+    reads only axis_names / shape)."""
+    return jax.sharding.AbstractMesh(SPEC_MESH_SHAPE, ("data", "model"))
 
 
 def dual_cache_specs(cfg, *, batch=4, capacity=4096, seq_shard=False):
@@ -142,7 +140,7 @@ def test_build_mesh_validation():
 # ==========================================================================
 PARITY_SUBPROC = r"""
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import jax
 jax.config.update("jax_enable_x64", False)
@@ -160,7 +158,7 @@ cfg = cfg.replace(wgkv=WGKVConfig(enabled=True, w_local=16, tau=0.1,
                                   sink=4))
 cfg = cfg.replace(sliding_window=min(cfg.sliding_window, 32))
 params = T.init_model(jax.random.PRNGKey(0), cfg)
-mesh = build_mesh("2x4")
+mesh = build_mesh("2x2")
 prompts = [list(range(7 + i, 39 + i)) for i in range(3)]
 
 engines = {}  # engines are reusable: jit caches amortize across drivers
@@ -238,7 +236,7 @@ def test_sharded_parity_vs_unsharded():
         mesh_run, flat_run = out[name]["mesh"], out[name]["flat"]
         assert mesh_run["sharded"] is True
         assert flat_run["sharded"] is False
-        assert mesh_run["devices"] == 8.0
+        assert mesh_run["devices"] == 4.0
         assert flat_run["devices"] is None
         assert mesh_run["tokens"] == flat_run["tokens"], name
         assert all(len(t) == 4 for t in mesh_run["tokens"])
@@ -273,17 +271,18 @@ def test_sharded_parity_vs_unsharded():
 def test_bench_serving_smoke_mesh(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"   # tests leave it off
     json_path = str(tmp_path / "BENCH_serving.json")
     env["BENCH_SERVING_JSON"] = json_path
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_serving",
-         "--backends", "wgkv,dense", "--smoke", "--mesh", "2x4"],
+         "--backends", "wgkv,dense", "--smoke", "--mesh", "2x2"],
         capture_output=True, text=True, env=env, timeout=1200,
         cwd=os.path.join(os.path.dirname(__file__), ".."))
     assert r.returncode == 0, r.stderr[-3000:]
     rec = json.load(open(json_path))
-    assert rec["trace"]["mesh"] == "2x4"
+    assert rec["trace"]["mesh"] == "2x2"
     for name in ("wgkv", "dense"):
         m = rec["backends"][name]
         assert m["requests"] == 4
@@ -304,7 +303,7 @@ def test_bench_serving_smoke_mesh(tmp_path):
 
 
 # ==========================================================================
-# in-process mesh tests (run under CI's 8 host devices)
+# in-process mesh tests (run under CI's 4 host devices)
 # ==========================================================================
 @needs_mesh
 def test_sharded_memory_snapshot_and_free():
