@@ -151,59 +151,61 @@ def lazy_promote_and_write(
     2. promote it to the Global Cache iff its stored g >= tau (per head);
     3. overwrite the slot with the new token; advance the pointer.
     """
-    b, h, w, d = cache.lk.shape
-    c = cache.budget
-    barange = jnp.arange(b)
-    # ---- victim ----------------------------------------------------------
-    vk = cache.lk[barange, :, cache.ptr]              # [B, H, hd]
-    vv = cache.lv[barange, :, cache.ptr]
-    vg = cache.lg[barange, :, cache.ptr]              # [B, H]
-    vpos = cache.lpos[barange, cache.ptr]             # [B]
-    victim_valid = vpos >= 0                          # [B]
-    promote = victim_valid[:, None] & (vg >= tau)     # [B, H]
-    can_write = promote & (cache.gcnt < c)
-    # ---- promotion: true scatter (touches one slot per head, not the
-    # whole cache — the jnp analogue of the paged in-place page write;
-    # §Perf P3 iteration: the previous one-hot `where` rewrote the entire
-    # global cache every step, tripling decode HBM traffic) --------------
-    dest = jnp.minimum(cache.gcnt, c - 1)             # [B, H]
-    bi = barange[:, None].repeat(h, 1)                # [B, H]
-    hi = jnp.arange(h)[None, :].repeat(b, 0)
-    old_k = cache.gk[bi, hi, dest]
-    old_v = cache.gv[bi, hi, dest]
-    old_p = cache.gpos[bi, hi, dest]
-    up_k = jnp.where(can_write[..., None], vk.astype(cache.gk.dtype), old_k)
-    up_v = jnp.where(can_write[..., None], vv.astype(cache.gv.dtype), old_v)
-    up_p = jnp.where(can_write, vpos[:, None], old_p)
-    gk = cache.gk.at[bi, hi, dest].set(up_k)
-    gv = cache.gv.at[bi, hi, dest].set(up_v)
-    gpos = cache.gpos.at[bi, hi, dest].set(up_p)
-    gcnt = cache.gcnt + can_write.astype(jnp.int32)
-    overflow = cache.overflow + (promote & ~can_write).astype(jnp.int32)
-    # incremental Quest metadata: fold the promoted key into the one page
-    # its append lands in (same touched-slot discipline as the gk scatter)
-    pkmin, pkmax = update_page_meta_on_write(
-        cache.pkmin, cache.pkmax, dest, vk, can_write)
-    # ---- write the new token into the ring (scatter at ptr) --------------
-    lk = cache.lk.at[barange, :, cache.ptr].set(k_new.astype(cache.lk.dtype))
-    lv = cache.lv.at[barange, :, cache.ptr].set(v_new.astype(cache.lv.dtype))
-    lg = cache.lg.at[barange, :, cache.ptr].set(g_new.astype(jnp.float32))
-    lpos = cache.lpos.at[barange, cache.ptr].set(cache.t)
-    return cache._replace(
-        lk=lk, lv=lv, lg=lg, lpos=lpos,
-        gk=gk, gv=gv, gpos=gpos, gcnt=gcnt, overflow=overflow,
-        pkmin=pkmin, pkmax=pkmax,
-        t=cache.t + 1, ptr=(cache.ptr + 1) % w,
-    )
+    with jax.named_scope("cache_write"):
+        b, h, w, d = cache.lk.shape
+        c = cache.budget
+        barange = jnp.arange(b)
+        # ---- victim ------------------------------------------------------
+        vk = cache.lk[barange, :, cache.ptr]              # [B, H, hd]
+        vv = cache.lv[barange, :, cache.ptr]
+        vg = cache.lg[barange, :, cache.ptr]              # [B, H]
+        vpos = cache.lpos[barange, cache.ptr]             # [B]
+        victim_valid = vpos >= 0                          # [B]
+        promote = victim_valid[:, None] & (vg >= tau)     # [B, H]
+        can_write = promote & (cache.gcnt < c)
+        # ---- promotion: true scatter (touches one slot per head, not the
+        # whole cache — the jnp analogue of the paged in-place page write;
+        # §Perf P3 iteration: the previous one-hot `where` rewrote the entire
+        # global cache every step, tripling decode HBM traffic) ----------
+        dest = jnp.minimum(cache.gcnt, c - 1)             # [B, H]
+        bi = barange[:, None].repeat(h, 1)                # [B, H]
+        hi = jnp.arange(h)[None, :].repeat(b, 0)
+        old_k = cache.gk[bi, hi, dest]
+        old_v = cache.gv[bi, hi, dest]
+        old_p = cache.gpos[bi, hi, dest]
+        up_k = jnp.where(can_write[..., None], vk.astype(cache.gk.dtype), old_k)
+        up_v = jnp.where(can_write[..., None], vv.astype(cache.gv.dtype), old_v)
+        up_p = jnp.where(can_write, vpos[:, None], old_p)
+        gk = cache.gk.at[bi, hi, dest].set(up_k)
+        gv = cache.gv.at[bi, hi, dest].set(up_v)
+        gpos = cache.gpos.at[bi, hi, dest].set(up_p)
+        gcnt = cache.gcnt + can_write.astype(jnp.int32)
+        overflow = cache.overflow + (promote & ~can_write).astype(jnp.int32)
+        # incremental Quest metadata: fold the promoted key into the one page
+        # its append lands in (same touched-slot discipline as the gk scatter)
+        pkmin, pkmax = update_page_meta_on_write(
+            cache.pkmin, cache.pkmax, dest, vk, can_write)
+        # ---- write the new token into the ring (scatter at ptr) ----------
+        lk = cache.lk.at[barange, :, cache.ptr].set(k_new.astype(cache.lk.dtype))
+        lv = cache.lv.at[barange, :, cache.ptr].set(v_new.astype(cache.lv.dtype))
+        lg = cache.lg.at[barange, :, cache.ptr].set(g_new.astype(jnp.float32))
+        lpos = cache.lpos.at[barange, cache.ptr].set(cache.t)
+        return cache._replace(
+            lk=lk, lv=lv, lg=lg, lpos=lpos,
+            gk=gk, gv=gv, gpos=gpos, gcnt=gcnt, overflow=overflow,
+            pkmin=pkmin, pkmax=pkmax,
+            t=cache.t + 1, ptr=(cache.ptr + 1) % w,
+        )
 
 
 def cache_kv_for_attention(cache: DualCache) -> Tuple[jax.Array, ...]:
     """Concatenate [global | local] K/V with validity mask for decode
     attention. Returns (k [B,H,C+W,hd], v, valid [B,H,C+W])."""
-    k = jnp.concatenate([cache.gk, cache.lk], axis=2)
-    v = jnp.concatenate([cache.gv, cache.lv], axis=2)
-    c = cache.budget
-    gvalid = jnp.arange(c)[None, None] < cache.gcnt[..., None]       # [B,H,C]
-    lvalid = (cache.lpos >= 0)[:, None, :]                           # [B,1,W]
-    lvalid = jnp.broadcast_to(lvalid, cache.lg.shape)
-    return k, v, jnp.concatenate([gvalid, lvalid], axis=2)
+    with jax.named_scope("kv_read"):
+        k = jnp.concatenate([cache.gk, cache.lk], axis=2)
+        v = jnp.concatenate([cache.gv, cache.lv], axis=2)
+        c = cache.budget
+        gvalid = jnp.arange(c)[None, None] < cache.gcnt[..., None]   # [B,H,C]
+        lvalid = (cache.lpos >= 0)[:, None, :]                       # [B,1,W]
+        lvalid = jnp.broadcast_to(lvalid, cache.lg.shape)
+        return k, v, jnp.concatenate([gvalid, lvalid], axis=2)

@@ -377,18 +377,21 @@ def attn_decode_wgkv(p: Params, cfg: ModelConfig, x_t: jax.Array,
     gathered path is off)."""
     b, d_model = x_t.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    x = x_t[:, None, :]  # [B,1,D]
-    q = _heads(x @ p["w_q"].astype(x.dtype), hq, hd)[:, :, 0]       # [B,Hq,hd]
-    k_pre = _heads(x @ p["w_k"].astype(x.dtype), hkv, hd)[:, :, 0]
-    v_new = _heads(x @ p["w_v"].astype(x.dtype), hkv, hd)[:, :, 0]
-    q, k_pre = _qk_norm(p, q[:, :, None], k_pre[:, :, None])
-    q, k_pre = q[:, :, 0], k_pre[:, :, 0]
-    q = _rope_single(cfg, q, cache.t)
-    k_new = _rope_single(cfg, k_pre, cache.t)
+    with jax.named_scope("proj"):
+        x = x_t[:, None, :]  # [B,1,D]
+        q = _heads(x @ p["w_q"].astype(x.dtype), hq, hd)[:, :, 0]   # [B,Hq,hd]
+        k_pre = _heads(x @ p["w_k"].astype(x.dtype), hkv, hd)[:, :, 0]
+        v_new = _heads(x @ p["w_v"].astype(x.dtype), hkv, hd)[:, :, 0]
+        q, k_pre = _qk_norm(p, q[:, :, None], k_pre[:, :, None])
+        q, k_pre = q[:, :, 0], k_pre[:, :, 0]
+        q = _rope_single(cfg, q, cache.t)
+        k_new = _rope_single(cfg, k_pre, cache.t)
     if gate_override is not None:
         g_new = gate_override
     else:
-        g_new = gate_scores(p["gate"], k_pre[:, :, None], k_new[:, :, None])[..., 0]
+        with jax.named_scope("write_gate"):
+            g_new = gate_scores(p["gate"], k_pre[:, :, None],
+                                k_new[:, :, None])[..., 0]
 
     # update first (promote victim, write self), then attend — see docstring
     new_cache = lazy_promote_and_write(cache, k_new, v_new, g_new, tau=cfg.wgkv.tau)
@@ -399,29 +402,33 @@ def attn_decode_wgkv(p: Params, cfg: ModelConfig, x_t: jax.Array,
         assert c % SEL.PAGE_SIZE == 0, \
             "global budget must be page-aligned for gathered Quest selection"
         p_pages = c // SEL.PAGE_SIZE
-        meta = SEL.PageMeta(
-            new_cache.pkmin, new_cache.pkmax,
-            SEL.page_valid_from_count(new_cache.gcnt, p_pages))
-        ids, sel_pages = SEL.topk_page_ids(q, meta, select_pages_k)
-        gk_s, gv_s, gvalid = SEL.gather_pages(
-            new_cache.gk, new_cache.gv, new_cache.gcnt, ids)
-        k_all = jnp.concatenate([gk_s, new_cache.lk], axis=2)
-        v_all = jnp.concatenate([gv_s, new_cache.lv], axis=2)
-        lvalid = jnp.broadcast_to((new_cache.lpos >= 0)[:, None, :],
-                                  new_cache.lg.shape)
-        valid = jnp.concatenate([gvalid, lvalid], axis=2)
+        with jax.named_scope("kv_read"):
+            meta = SEL.PageMeta(
+                new_cache.pkmin, new_cache.pkmax,
+                SEL.page_valid_from_count(new_cache.gcnt, p_pages))
+            ids, sel_pages = SEL.topk_page_ids(q, meta, select_pages_k)
+            gk_s, gv_s, gvalid = SEL.gather_pages(
+                new_cache.gk, new_cache.gv, new_cache.gcnt, ids)
+            k_all = jnp.concatenate([gk_s, new_cache.lk], axis=2)
+            v_all = jnp.concatenate([gv_s, new_cache.lv], axis=2)
+            lvalid = jnp.broadcast_to((new_cache.lpos >= 0)[:, None, :],
+                                      new_cache.lg.shape)
+            valid = jnp.concatenate([gvalid, lvalid], axis=2)
     else:
         k_all, v_all, valid = cache_kv_for_attention(new_cache)      # [B,H,C+W,*]
         if token_select_fn is not None:
-            valid = valid & token_select_fn(new_cache, q)
-    grp = hq // hkv
-    qg = q.reshape(b, hkv, grp, hd)
-    logits = jnp.einsum("bhgd,bhkd->bhgk", qg, k_all).astype(jnp.float32)
-    logits = logits * (hd ** -0.5)
-    logits = jnp.where(valid[:, :, None], logits, M.NEG_INF)
-    wts = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", wts.astype(v_all.dtype), v_all)
-    y = o.reshape(b, hq * hd) @ p["w_o"].astype(x_t.dtype)
+            with jax.named_scope("kv_read"):
+                valid = valid & token_select_fn(new_cache, q)
+    with jax.named_scope("attention"):
+        grp = hq // hkv
+        qg = q.reshape(b, hkv, grp, hd)
+        logits = jnp.einsum("bhgd,bhkd->bhgk", qg, k_all).astype(jnp.float32)
+        logits = logits * (hd ** -0.5)
+        logits = jnp.where(valid[:, :, None], logits, M.NEG_INF)
+        wts = jax.nn.softmax(logits, axis=-1)
+        o = jnp.einsum("bhgk,bhkd->bhgd", wts.astype(v_all.dtype), v_all)
+    with jax.named_scope("proj"):
+        y = o.reshape(b, hq * hd) @ p["w_o"].astype(x_t.dtype)
     return y, new_cache, g_new, sel_pages
 
 
@@ -431,28 +438,32 @@ def attn_decode_dense(p: Params, cfg: ModelConfig, x_t: jax.Array,
     """Full-attention baseline decode step. x_t: [B, D]."""
     b, _ = x_t.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    x = x_t[:, None, :]
-    q = _heads(x @ p["w_q"].astype(x.dtype), hq, hd)[:, :, 0]
-    k_pre = _heads(x @ p["w_k"].astype(x.dtype), hkv, hd)[:, :, 0]
-    v_new = _heads(x @ p["w_v"].astype(x.dtype), hkv, hd)[:, :, 0]
-    q, k_pre = _qk_norm(p, q[:, :, None], k_pre[:, :, None])
-    q, k_pre = q[:, :, 0], k_pre[:, :, 0]
-    q = _rope_single(cfg, q, cache.t)
-    k_new = _rope_single(cfg, k_pre, cache.t)
-    cache = dense_cache_append(cache, k_new, v_new)
-    s = cache.k.shape[2]
-    pos = jnp.arange(s)[None]                                       # [1, S]
-    valid = pos < cache.t[:, None]
-    if window is not None:
-        valid = valid & (pos >= cache.t[:, None] - window)
-    grp = hq // hkv
-    qg = q.reshape(b, hkv, grp, hd)
-    logits = jnp.einsum("bhgd,bhkd->bhgk", qg, cache.k).astype(jnp.float32)
-    logits = logits * (hd ** -0.5)
-    logits = jnp.where(valid[:, None, None], logits, M.NEG_INF)
-    wts = jax.nn.softmax(logits, -1)
-    o = jnp.einsum("bhgk,bhkd->bhgd", wts.astype(cache.v.dtype), cache.v)
-    y = o.reshape(b, hq * hd) @ p["w_o"].astype(x_t.dtype)
+    with jax.named_scope("proj"):
+        x = x_t[:, None, :]
+        q = _heads(x @ p["w_q"].astype(x.dtype), hq, hd)[:, :, 0]
+        k_pre = _heads(x @ p["w_k"].astype(x.dtype), hkv, hd)[:, :, 0]
+        v_new = _heads(x @ p["w_v"].astype(x.dtype), hkv, hd)[:, :, 0]
+        q, k_pre = _qk_norm(p, q[:, :, None], k_pre[:, :, None])
+        q, k_pre = q[:, :, 0], k_pre[:, :, 0]
+        q = _rope_single(cfg, q, cache.t)
+        k_new = _rope_single(cfg, k_pre, cache.t)
+    with jax.named_scope("cache_write"):
+        cache = dense_cache_append(cache, k_new, v_new)
+    with jax.named_scope("attention"):
+        s = cache.k.shape[2]
+        pos = jnp.arange(s)[None]                                   # [1, S]
+        valid = pos < cache.t[:, None]
+        if window is not None:
+            valid = valid & (pos >= cache.t[:, None] - window)
+        grp = hq // hkv
+        qg = q.reshape(b, hkv, grp, hd)
+        logits = jnp.einsum("bhgd,bhkd->bhgk", qg, cache.k).astype(jnp.float32)
+        logits = logits * (hd ** -0.5)
+        logits = jnp.where(valid[:, None, None], logits, M.NEG_INF)
+        wts = jax.nn.softmax(logits, -1)
+        o = jnp.einsum("bhgk,bhkd->bhgd", wts.astype(cache.v.dtype), cache.v)
+    with jax.named_scope("proj"):
+        y = o.reshape(b, hq * hd) @ p["w_o"].astype(x_t.dtype)
     return y, cache
 
 

@@ -253,7 +253,8 @@ def _quest_mask(cfg: ModelConfig, cache: DualCache, q: jax.Array,
 
 def _attn_block_decode(p, cfg: ModelConfig, bt: str, x_t, cache, *,
                        opts: DecodeOptions, obs=None, moe_groups: int):
-    xin = _norm(cfg, p["ln1"], x_t[:, None])[:, 0]
+    with jax.named_scope("proj"):
+        xin = _norm(cfg, p["ln1"], x_t[:, None])[:, 0]
     self_cache = cache["self"] if bt == "attn_cross" else cache
     window = cfg.sliding_window if bt == "local_attn" else None
     trig = jnp.zeros((), jnp.float32)
@@ -287,14 +288,18 @@ def _attn_block_decode(p, cfg: ModelConfig, bt: str, x_t, cache, *,
                                  _norm(cfg, p["ln_x"], x_t[:, None]),
                                  cache["cross"])[:, 0]
         new_cache = {"self": new_cache, "cross": cache["cross"]}
-    if bt == "attn_moe":
-        y, _ = MoE.moe_ffn(p["moe"], cfg, _norm(cfg, p["ln2"], x_t[:, None]),
-                           groups=moe_groups)
-        x_t = x_t + y[:, 0]
-    elif bt == "attn_cross" or cfg.arch_type == "audio":
-        x_t = x_t + L.gelu_mlp(p["mlp"], _norm(cfg, p["ln2"], x_t[:, None]))[:, 0]
-    else:
-        x_t = x_t + L.swiglu(p["mlp"], _norm(cfg, p["ln2"], x_t[:, None]))[:, 0]
+    with jax.named_scope("mlp"):
+        if bt == "attn_moe":
+            y, _ = MoE.moe_ffn(p["moe"], cfg,
+                               _norm(cfg, p["ln2"], x_t[:, None]),
+                               groups=moe_groups)
+            x_t = x_t + y[:, 0]
+        elif bt == "attn_cross" or cfg.arch_type == "audio":
+            x_t = x_t + L.gelu_mlp(p["mlp"],
+                                   _norm(cfg, p["ln2"], x_t[:, None]))[:, 0]
+        else:
+            x_t = x_t + L.swiglu(p["mlp"],
+                                 _norm(cfg, p["ln2"], x_t[:, None]))[:, 0]
     return x_t, new_cache, obs, trig, adm, selp
 
 
@@ -326,7 +331,8 @@ def decode_step(params: Params, cfg: ModelConfig, token: jax.Array,
                 ) -> Tuple[jax.Array, CacheTree, Dict[str, jax.Array]]:
     """token: [B] int32 -> (logits [B, V], new caches, stats)."""
     dt = jnp.dtype(cfg.dtype)
-    x = L.embed(params["embed"], token[:, None], dt)[:, 0]  # [B, D]
+    with jax.named_scope("embed"):
+        x = L.embed(params["embed"], token[:, None], dt)[:, 0]  # [B, D]
     b = x.shape[0]
     t = caches["t"]
     if cfg.is_encdec:
@@ -393,13 +399,16 @@ def decode_step(params: Params, cfg: ModelConfig, token: jax.Array,
 
     xs = (params["blocks"], caches["blocks"], caches["obs"]) if has_obs \
         else (params["blocks"], caches["blocks"])
-    (x, trig_sum, adm_sum, adm_n, sel_sum), ys = jax.lax.scan(
-        body, (x, trig_sum, adm_sum, adm_n, sel_sum), xs, unroll=scan_unroll)
+    with jax.named_scope("layer_scan"):
+        (x, trig_sum, adm_sum, adm_n, sel_sum), ys = jax.lax.scan(
+            body, (x, trig_sum, adm_sum, adm_n, sel_sum), xs,
+            unroll=scan_unroll)
     new_caches["blocks"] = ys[0]
     if has_obs:
         new_caches["obs"] = ys[1]
-    hidden = _norm(cfg, params["ln_f"], x[:, None])[:, 0]
-    logits = L.unembed(params["embed"], hidden)
+    with jax.named_scope("lm_head"):
+        hidden = _norm(cfg, params["ln_f"], x[:, None])[:, 0]
+        logits = L.unembed(params["embed"], hidden)
     return logits, new_caches, {
         "evict_triggers": trig_sum.mean(),
         # per-row [B] so serving backends can re-sync the paged mirror for
@@ -496,16 +505,18 @@ def prefill_extend_ragged(params: Params, cfg: ModelConfig,
         logits, new, st = decode_step(params, cfg, tok, old,
                                       moe_groups=moe_groups, opts=opts,
                                       scan_unroll=scan_unroll)
-        merged = jax.tree_util.tree_map_with_path(keep, new, old)
-        last_logits = jnp.where(active[:, None], logits, last_logits)
+        with jax.named_scope("row_keep"):
+            merged = jax.tree_util.tree_map_with_path(keep, new, old)
+            last_logits = jnp.where(active[:, None], logits, last_logits)
         trig = jnp.where(active, st["evict_trigger_rows"], 0.0)
         adm = jnp.where(active, st["mean_admission"], 0.0)
         selp = jnp.where(active, st["selected_pages_rows"], 0.0)
         return (merged, last_logits), (trig, adm, selp)
 
     init = (caches, jnp.zeros(logits_s.shape, logits_s.dtype))
-    (caches, last_logits), (trig, adm, selp) = jax.lax.scan(
-        body, init, (tokens.T, active_mat))
+    with jax.named_scope("position_scan"):
+        (caches, last_logits), (trig, adm, selp) = jax.lax.scan(
+            body, init, (tokens.T, active_mat))
     return last_logits, caches, {"evict_trigger_rows": trig.sum(axis=0),
                                  "adm_sum_rows": adm.sum(axis=0),
                                  "selected_pages_rows": selp.sum(axis=0)}

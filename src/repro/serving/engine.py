@@ -188,7 +188,11 @@ class Engine(ShardedDecodeMixin):
                       # over kv heads, summed over attention layers and
                       # decode row-steps) and the wall time of
                       # selection-enabled fused steps
-                      "selected_pages": 0.0, "selection_time_s": 0.0}
+                      "selected_pages": 0.0, "selection_time_s": 0.0,
+                      # collect's wait for the device: the device_get that
+                      # blocks on the fused call (the rest of collect is
+                      # host work)
+                      "collect_wait_time_s": 0.0}
         # observability handle; the Orchestrator overwrites this with its
         # own tracer so engine-side sub-phase spans share its timeline
         self.tracer = NULL_TRACER
@@ -511,50 +515,56 @@ class Engine(ShardedDecodeMixin):
                     self._slot_prefix[t.slot] = None
                 self._resident[t.slot] = True
                 self._slot_gen[t.slot] += 1
-        # ragged feed: prompt chunks left-aligned per row; S pinned to the
-        # chunk width (or w-aligned when unchunked) for compile stability
-        takes = [len(t.prompt) - t.pos if max_tokens is None
-                 else min(len(t.prompt) - t.pos, max_tokens) for t in tasks]
-        if not tasks:
-            s = 1
-        elif max_tokens is None:
-            q = self._w_align
-            s = -(-max(takes) // q) * q
-        else:
-            s = max_tokens
-        toks = np.zeros((self.slots, s), np.int32)
-        lengths = np.zeros((self.slots,), np.int32)
-        use_dev = np.zeros((self.slots,), bool)
-        for t, take in zip(tasks, takes):
-            toks[t.slot, :take] = t.prompt[t.pos:t.pos + take]
-            lengths[t.slot] = take
-        decode_rows = tuple(sl for sl in range(self.slots)
-                            if decode and self.live[sl] and lengths[sl] == 0)
-        for sl in decode_rows:
-            lengths[sl] = 1
-            use_dev[sl] = True
-        # a dead row decodes masked but still feeds its last_token; a
-        # nonzero token there is a missed free_slot reset (a stale replay
-        # of the retired request's final token)
-        assert all(self.last_token[sl] == 0 for sl in range(self.slots)
-                   if not self.live[sl] and lengths[sl] == 0), \
-            "stale last_token on a dead row"
-        self._pre_fused_dispatch(
-            [(t.slot, take) for t, take in zip(tasks, takes)], decode_rows)
-        # fixed-shape padding accounting: the compiled step always spans
-        # ``slots`` rows; only length>0 rows do real work
-        self.stats["fused_slot_rows"] += float(self.slots)
-        self.stats["fused_active_rows"] += float(int((lengths > 0).sum()))
-        # decode-only ticks run the gathered top-K selection variant when
-        # configured; any prompt chunk aboard forces the full path (its
-        # decode rows ride that mixed call with full attention)
-        use_sel = self._fused_sel is not None and not tasks
-        self.key, sk = jax.random.split(self.key)
-        before = self.caches
-        mirror = self.mirror
-        feed = (jnp.asarray(toks), jnp.asarray(lengths), self._tok_dev,
-                jnp.asarray(use_dev), sk[None])
-        with self.tracer.device_scope("fused_step"):
+        with self.tracer.span("fused_feed"):
+            # ragged feed: prompt chunks left-aligned per row; S pinned to
+            # the chunk width (or w-aligned when unchunked) for compile
+            # stability
+            takes = [len(t.prompt) - t.pos if max_tokens is None
+                     else min(len(t.prompt) - t.pos, max_tokens)
+                     for t in tasks]
+            if not tasks:
+                s = 1
+            elif max_tokens is None:
+                q = self._w_align
+                s = -(-max(takes) // q) * q
+            else:
+                s = max_tokens
+            toks = np.zeros((self.slots, s), np.int32)
+            lengths = np.zeros((self.slots,), np.int32)
+            use_dev = np.zeros((self.slots,), bool)
+            for t, take in zip(tasks, takes):
+                toks[t.slot, :take] = t.prompt[t.pos:t.pos + take]
+                lengths[t.slot] = take
+            decode_rows = tuple(
+                sl for sl in range(self.slots)
+                if decode and self.live[sl] and lengths[sl] == 0)
+            for sl in decode_rows:
+                lengths[sl] = 1
+                use_dev[sl] = True
+            # a dead row decodes masked but still feeds its last_token; a
+            # nonzero token there is a missed free_slot reset (a stale
+            # replay of the retired request's final token)
+            assert all(self.last_token[sl] == 0 for sl in range(self.slots)
+                       if not self.live[sl] and lengths[sl] == 0), \
+                "stale last_token on a dead row"
+            self._pre_fused_dispatch(
+                [(t.slot, take) for t, take in zip(tasks, takes)],
+                decode_rows)
+            # fixed-shape padding accounting: the compiled step always
+            # spans ``slots`` rows; only length>0 rows do real work
+            self.stats["fused_slot_rows"] += float(self.slots)
+            self.stats["fused_active_rows"] += float(
+                int((lengths > 0).sum()))
+            # decode-only ticks run the gathered top-K selection variant
+            # when configured; any prompt chunk aboard forces the full path
+            # (its decode rows ride that mixed call with full attention)
+            use_sel = self._fused_sel is not None and not tasks
+            self.key, sk = jax.random.split(self.key)
+            before = self.caches
+            mirror = self.mirror
+            feed = (jnp.asarray(toks), jnp.asarray(lengths), self._tok_dev,
+                    jnp.asarray(use_dev), sk[None])
+        with self.tracer.span("fused_call"):
             if use_sel:
                 with self.tracer.span("selection", k=self._sel_k,
                                       rows=len(decode_rows)):
@@ -563,40 +573,44 @@ class Engine(ShardedDecodeMixin):
             else:
                 logits, self.caches, st = self._fused(
                     self.params, feed, before)
-        sampled = st["sampled"]
-        # host bookkeeping at dispatch (teacher-forced, deterministic):
-        # advance positions; a finishing row goes live NOW so the next
-        # dispatched step can already decode it
-        finishing = []
-        for t, take in zip(tasks, takes):
-            t.pos += take
-            fin = t.pos >= len(t.prompt)
-            finishing.append(fin)
-            if fin:
-                self.live[t.slot] = True
-        # only rows that really sampled this step (decode rows + finishing
-        # prefill rows) update the device feed; a masked/mid-prefill row's
-        # sampled value is garbage and must not clobber its feed token
-        fed = np.zeros((self.slots,), bool)
-        for sl in decode_rows:
-            fed[sl] = True
-        for t, fin in zip(tasks, finishing):
-            fed[t.slot] = fin
-        self._tok_dev = jnp.where(jnp.asarray(fed), sampled, self._tok_dev)
-        fulls = [max_tokens is not None and take == max_tokens
-                 for take in takes]
-        return FusedStep(
-            tokens=sampled, stats=st,
-            before=before if mirror else None,
-            # ``after`` is kept unconditionally (a tree of references, no
-            # device copy): prefix capture snapshots it at collect even
-            # when the host paged mirror is off (timed/meshed engines)
-            after=self.caches,
-            live=tuple(self.live), gen=tuple(self._slot_gen),
-            tasks=tuple(tasks), takes=tuple(takes), fulls=tuple(fulls),
-            finishing=tuple(finishing), decode_rows=decode_rows,
-            had_prefill=bool(tasks), t_dispatch=t0, selection=use_sel,
-            logits=logits)
+        with self.tracer.span("fused_bookkeep"):
+            sampled = st["sampled"]
+            # host bookkeeping at dispatch (teacher-forced, deterministic):
+            # advance positions; a finishing row goes live NOW so the next
+            # dispatched step can already decode it
+            finishing = []
+            for t, take in zip(tasks, takes):
+                t.pos += take
+                fin = t.pos >= len(t.prompt)
+                finishing.append(fin)
+                if fin:
+                    self.live[t.slot] = True
+            # only rows that really sampled this step (decode rows +
+            # finishing prefill rows) update the device feed; a
+            # masked/mid-prefill row's sampled value is garbage and must
+            # not clobber its feed token
+            fed = np.zeros((self.slots,), bool)
+            for sl in decode_rows:
+                fed[sl] = True
+            for t, fin in zip(tasks, finishing):
+                fed[t.slot] = fin
+            self._tok_dev = jnp.where(jnp.asarray(fed), sampled,
+                                      self._tok_dev)
+            fulls = [max_tokens is not None and take == max_tokens
+                     for take in takes]
+            return FusedStep(
+                tokens=sampled, stats=st,
+                before=before if mirror else None,
+                # ``after`` is kept unconditionally (a tree of references,
+                # no device copy): prefix capture snapshots it at collect
+                # even when the host paged mirror is off (timed/meshed
+                # engines)
+                after=self.caches,
+                live=tuple(self.live), gen=tuple(self._slot_gen),
+                tasks=tuple(tasks), takes=tuple(takes), fulls=tuple(fulls),
+                finishing=tuple(finishing), decode_rows=decode_rows,
+                had_prefill=bool(tasks), t_dispatch=t0, selection=use_sel,
+                logits=logits)
 
     def _pre_fused_dispatch(self, prefill: List[Tuple[int, int]],
                             decode_rows: Tuple[int, ...]) -> None:
@@ -616,11 +630,15 @@ class Engine(ShardedDecodeMixin):
         re-opened) while the step was in flight."""
         assert not step.collected, "in-flight step collected twice"
         step.collected = True
-        nxt, trig, adm, selp, kvr = jax.device_get(  # jaxlint: allow-sync(collect is THE designated sync point of the dispatch/collect contract)
-            (step.tokens, step.stats["evict_trigger_rows"],
-             step.stats["adm_sum_rows"],
-             step.stats["selected_pages_rows"],
-             step.stats["kv_tokens_rows"]))
+        with self.tracer.span("collect_wait"):
+            t_wait = time.perf_counter()
+            nxt, trig, adm, selp, kvr = jax.device_get(  # jaxlint: allow-sync(collect is THE designated sync point of the dispatch/collect contract)
+                (step.tokens, step.stats["evict_trigger_rows"],
+                 step.stats["adm_sum_rows"],
+                 step.stats["selected_pages_rows"],
+                 step.stats["kv_tokens_rows"]))
+            self.stats["collect_wait_time_s"] += \
+                time.perf_counter() - t_wait
         # refresh the host kv accounting memory_snapshot reads (rows whose
         # slot churned while the step was in flight keep their newer value)
         for sl in range(self.slots):

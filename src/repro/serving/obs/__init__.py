@@ -10,13 +10,18 @@ no-op :data:`NULL_TRACER` and pays one branch per instrumentation site):
                orchestrator Telemetry sits on top of it (its ``counters``
                dict is a live :class:`CounterView`)
   export.py  — Chrome-trace/Perfetto JSON exporter + structural
-               validator (CI gates emitted artifacts through it), and
-               the ``jax.profiler.TraceAnnotation`` device bridge lives
-               on the tracer itself (``annotate_device=True``)
+               validator (CI gates emitted artifacts through it)
+
+The device bridge is ``Tracer(annotate_device=True)``: every tick phase,
+engine sub-span and ``gc`` span then also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler trace
+shows the host's work on the device trace's own clock.
 
 Wired in by: serving/orchestrator/scheduler.py (tick phases + request
-lifecycle), serving/engine.py (prefill_open / prefill_extend_ragged /
-decode dispatch sub-phases on every backend), launch/serve.py
+lifecycle), serving/orchestrator/session.py (``gc`` spans while a
+session holds the tracer), serving/engine.py (fused_open /
+prefix_splice / fused_feed / fused_call / fused_bookkeep / collect_wait /
+prefill_extend_ragged sub-phases on every backend), launch/serve.py
 (``--trace-out`` / ``--metrics-interval``), benchmarks/bench_serving.py
 (per-backend trace artifacts + phase-time breakdown columns).
 """

@@ -10,10 +10,14 @@ spent*. The tracer answers both with two families of spans:
     -> insert -> decode -> finish/cancel/deadline`` — the lifecycle the
     orchestrator drives;
   * **engine lane** (one lane per tick loop): ``memory_sample``,
-    ``admit``, ``fused_step`` (the one jitted megabatch dispatch, with
-    a ``selection`` sub-span when top-K page selection is active),
-    ``collect``, ``evict`` — the per-tick phase decomposition the
-    ROADMAP's fused megabatch / prefix-cache items need as evidence.
+    ``admit``, ``fused_step`` (the one jitted megabatch dispatch: its
+    ``fused_open``/``prefix_splice`` row splices, then ``fused_feed``,
+    ``fused_call`` — with a ``selection`` sub-span when top-K page
+    selection is active — and ``fused_bookkeep``), ``collect`` (its
+    ``collect_wait`` for the device, the rest host work), ``evict``,
+    and ``gc`` for each garbage collection (:meth:`Tracer.watch_gc`) —
+    the per-tick phase decomposition the ROADMAP's fused megabatch /
+    prefix-cache items need as evidence.
 
 Design constraints:
 
@@ -27,9 +31,10 @@ Design constraints:
     pre-allocated context manager and ``add``/``instant`` return before
     touching the clock, so un-traced serving pays one attribute load +
     one branch per call site (asserted by the overhead test).
-  * **Device bridge**: with ``annotate_device=True`` every ``span()``
-    also enters a ``jax.profiler.TraceAnnotation`` scope, so host spans
-    line up with device traces in a profiler timeline.
+  * **Device bridge**: with ``annotate_device=True`` every ``span()``,
+    every orchestrator tick phase and every ``gc`` span also enters a
+    ``jax.profiler.TraceAnnotation`` of the same name, so the host's
+    work shows in a profiler trace on the device trace's own clock.
 
 Timestamps come from the injected ``clock`` (monotonic by default) —
 deterministic-clock tests drive the tracer and the orchestrator from the
@@ -39,6 +44,7 @@ same fake clock. Export to Chrome-trace JSON lives in
 from __future__ import annotations
 
 import collections
+import gc
 import time
 from typing import Callable, Deque, Dict, List, NamedTuple, Optional, Tuple
 
@@ -111,7 +117,8 @@ class Tracer:
 
     ``capacity`` bounds resident spans (oldest dropped); ``enabled=False``
     short-circuits every entry point (see :data:`NULL_TRACER`);
-    ``annotate_device`` additionally wraps ``span()`` bodies in
+    ``annotate_device`` (enabled tracers only) additionally wraps
+    ``span()`` bodies, tick phases and ``gc`` spans in
     ``jax.profiler.TraceAnnotation`` so a device profile shows the same
     phase names as the host trace."""
 
@@ -122,9 +129,12 @@ class Tracer:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.clock = clock
         self.enabled = enabled
-        self.annotate_device = annotate_device
+        self.annotate_device = enabled and annotate_device
         self.spans: Deque[Span] = collections.deque(maxlen=capacity)
         self.emitted = 0        # total ever recorded (>= len(spans))
+        self._gc_cb = None      # the registered gc callback, if any
+        self._gc_t0 = 0.0
+        self._gc_ann = None
 
     # ---- recording -------------------------------------------------------
     def span(self, name: str, *, cat: str = CAT_ENGINE,
@@ -154,15 +164,35 @@ class Tracer:
         self.spans.append(Span(name, cat, lane, t, t, args or None))
         self.emitted += 1
 
-    def device_scope(self, name: str):
-        """``jax.profiler.TraceAnnotation`` context when device
-        annotation is on, else the shared no-op — engines wrap their
-        jitted dispatches with this so device profiles carry the serving
-        phase names without the host-span overhead per step."""
-        if not (self.enabled and self.annotate_device):
-            return _NULL_SPAN
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
+    # ---- garbage collections ------------------------------------------
+    def watch_gc(self) -> None:
+        """Record each garbage collection as a ``gc`` span on the tick
+        lane (args: the generation collected) until :meth:`unwatch_gc`.
+        A disabled tracer registers nothing."""
+        if self.enabled and self._gc_cb is None:
+            self._gc_cb = self._on_gc
+            gc.callbacks.append(self._gc_cb)
+
+    def unwatch_gc(self) -> None:
+        """Stop recording garbage collections."""
+        if self._gc_cb is not None:
+            gc.callbacks.remove(self._gc_cb)
+            self._gc_cb = None
+
+    def _on_gc(self, phase: str, info: Dict) -> None:
+        if phase == "start":
+            if self.annotate_device:
+                from jax.profiler import TraceAnnotation
+                self._gc_ann = TraceAnnotation("gc")
+                self._gc_ann.__enter__()
+            self._gc_t0 = self.clock()
+            return
+        t1 = self.clock()
+        if self._gc_ann is not None:
+            self._gc_ann.__exit__(None, None, None)
+            self._gc_ann = None
+        self.add("gc", self._gc_t0, t1,
+                 args={"generation": info["generation"]})
 
     # ---- views -----------------------------------------------------------
     def drain(self) -> List[Span]:

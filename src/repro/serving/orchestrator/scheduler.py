@@ -76,15 +76,21 @@ _ENGINE_STAT_KEYS = ("evict_triggers", "decode_adm_sum",
                      # rows per fused dispatch -> fused_padding_frac)
                      "fused_slot_rows", "fused_active_rows",
                      # decode-time page selection (gathered top-K ticks)
-                     "selected_pages", "selection_time_s")
+                     "selected_pages", "selection_time_s",
+                     # collect's device wait (the rest of collect_time_s
+                     # is host work)
+                     "collect_wait_time_s")
 
 
 class _Phase:
     """Times one tick phase against the orchestrator's clock, folding the
     duration into a telemetry counter AND emitting an engine-lane tracer
-    span. With the default :data:`NULL_TRACER` the span add is a no-op
-    branch, so always-on phase accounting costs two clock reads."""
-    __slots__ = ("orch", "name", "counter", "args", "t0")
+    span; under a tracer with ``annotate_device`` the phase is also a
+    ``jax.profiler.TraceAnnotation`` of the same name, so it shows in a
+    profiler trace on the device's clock. With the default
+    :data:`NULL_TRACER` the span add is a no-op branch, so always-on phase
+    accounting costs two clock reads."""
+    __slots__ = ("orch", "name", "counter", "args", "t0", "ann")
 
     def __init__(self, orch: "Orchestrator", name: str, counter: str,
                  args: Optional[Dict]):
@@ -92,13 +98,20 @@ class _Phase:
         self.name = name
         self.counter = counter
         self.args = args
+        self.ann = None
 
     def __enter__(self) -> "_Phase":
+        if self.orch.tracer.annotate_device:
+            from jax.profiler import TraceAnnotation
+            self.ann = TraceAnnotation(self.name)
+            self.ann.__enter__()
         self.t0 = self.orch.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = self.orch.clock()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         self.orch.telemetry.bump(self.counter, t1 - self.t0)
         self.orch.tracer.add(self.name, self.t0, t1, cat=CAT_ENGINE,
                              lane=(LANE_TICK, 0), args=self.args)

@@ -139,6 +139,9 @@ class ServeSession:
             sched = SchedulerConfig(dispatch_ahead=1)
         self.orchestrator = Orchestrator(engine, sched=sched,
                                          max_pending=max_pending, **orch_kw)
+        # garbage collections land on the tracer's tick lane while the
+        # session holds it (a no-op on the default NULL_TRACER)
+        self.orchestrator.tracer.watch_gc()
         self._closed = False
 
     # ---- submission --------------------------------------------------
@@ -173,6 +176,7 @@ class ServeSession:
         if not self._closed:
             self.orchestrator.drain()
             self.orchestrator.telemetry.stop()
+            self.orchestrator.tracer.unwatch_gc()
             self._closed = True
 
     def __enter__(self) -> "ServeSession":

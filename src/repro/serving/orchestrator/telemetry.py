@@ -112,6 +112,9 @@ class Telemetry:
                 ("fused_slot_rows", 0), ("fused_active_rows", 0),
                 # decode-time page selection (gathered top-K fused ticks)
                 ("selected_pages", 0.0), ("selection_time_s", 0.0),
+                # collect's wait for the device (engine stat): the rest
+                # of collect_time_s is host work
+                ("collect_wait_time_s", 0.0),
                 # content-addressed prefix store (admission-gated
                 # shared-context reuse): hit/miss at admission, LRU
                 # evictions, and the store's current byte footprint
@@ -271,6 +274,8 @@ class Telemetry:
         out["fused_time_s"] = float(c.get("fused_time_s", 0.0))
         out["fused_prefill_time_s"] = float(c.get("fused_prefill_time_s", 0.0))
         out["selection_time_s"] = float(c.get("selection_time_s", 0.0))
+        # the device wait inside collect_time_s (engine stat)
+        out["collect_wait_time_s"] = float(c.get("collect_wait_time_s", 0.0))
         out["tick_time_s"] = float(c.get("tick_time_s", 0.0))
         out["phase_sum_s"] = sum(float(c.get(k, 0.0))
                                  for k in PHASE_TIME_KEYS)

@@ -192,18 +192,24 @@ class ShardedDecodeMixin:
         opts = self.opts if opts is None else opts
 
         def fn(params, feed, caches):  # jaxlint: masked-scan-body
-            tokens, lengths, tok_dev, use_dev, key = feed
-            tokens = tokens.at[:, 0].set(
-                jnp.where(use_dev, tok_dev, tokens[:, 0]))
-            last_logits, caches, st = I.prefill_extend_ragged(
-                params, self.cfg, tokens, lengths, caches, opts=opts)
-            sampled = sample(key[0], last_logits, temperature=temperature)
-            # per-row resident KV tokens computed IN-JIT from the post-step
-            # tree and pulled with collect's one sync, so memory_snapshot
-            # reads host state only (the PR 9 allow-sync debt is gone)
-            kv_rows = self._kv_tokens_device(caches)
-            return last_logits, caches, {**st, "sampled": sampled,
-                                         "kv_tokens_rows": kv_rows}
+            # named scopes label the device trace's ops by the piece of the
+            # step they belong to (models/inference.py, attention.py,
+            # core/dual_cache.py); "fused_step" catches the rest
+            with jax.named_scope("fused_step"):
+                tokens, lengths, tok_dev, use_dev, key = feed
+                tokens = tokens.at[:, 0].set(
+                    jnp.where(use_dev, tok_dev, tokens[:, 0]))
+                last_logits, caches, st = I.prefill_extend_ragged(
+                    params, self.cfg, tokens, lengths, caches, opts=opts)
+                with jax.named_scope("sample"):
+                    sampled = sample(key[0], last_logits,
+                                     temperature=temperature)
+                    # per-row resident KV tokens computed IN-JIT from the
+                    # post-step tree and pulled with collect's one sync, so
+                    # memory_snapshot reads host state only
+                    kv_rows = self._kv_tokens_device(caches)
+                return last_logits, caches, {**st, "sampled": sampled,
+                                             "kv_tokens_rows": kv_rows}
 
         return jax.jit(fn) if self.mesh is None \
             else self._mesh_jit(fn, kind=kind)

@@ -41,3 +41,25 @@ def make_cfg(arch: str = "qwen3-0.6b", **wgkv_kw):
     if cfg.wgkv.enabled:
         cfg = cfg.replace(wgkv=small_wgkv(**wgkv_kw))
     return cfg.replace(sliding_window=min(cfg.sliding_window, 32))
+
+
+# the named scopes of the fused serving step (serving/sharded.py and the
+# model code under it), each of which the compiled step's op_name
+# metadata must hold; the dense cache has no write gate and reads its
+# cache inside attention
+FUSED_SCOPES = ("embed", "proj", "write_gate", "cache_write", "kv_read",
+                "attention", "mlp", "lm_head", "row_keep", "layer_scan",
+                "position_scan", "sample", "fused_step")
+DENSE_ABSENT_SCOPES = ("write_gate", "kv_read")
+
+
+def scopes_in(hlo_text: str) -> set:
+    """Every name-stack component of the op_name metadata in an HLO text."""
+    import re
+    return {part for name in re.findall(r'op_name="([^"]*)"', hlo_text)
+            for part in name.split("/")}
+
+
+def expected_scopes(backend: str) -> set:
+    absent = DENSE_ABSENT_SCOPES if backend == "dense" else ()
+    return set(FUSED_SCOPES) - set(absent)

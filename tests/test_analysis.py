@@ -542,23 +542,23 @@ def test_repo_tree_lints_clean_with_committed_baseline():
 
 SEEDED = [
     ("JL001", "src/repro/serving/engine.py",
-     '        self.stats["fused_slot_rows"] += float(self.slots)',
-     '        self.stats["fused_slot_rows"] += float(self.slots)\n'
-     '        _dbg = float(jnp.sum(self._tok_dev))'),
+     '            self.stats["fused_slot_rows"] += float(self.slots)',
+     '            self.stats["fused_slot_rows"] += float(self.slots)\n'
+     '            _dbg = float(jnp.sum(self._tok_dev))'),
     ("JL002", "src/repro/serving/engine.py",
-     '        self.stats["fused_slot_rows"] += float(self.slots)',
-     '        self.stats["fused_slot_rows"] += float(self.slots)\n'
-     '        _cat = jnp.concatenate([jnp.zeros((1,)), jnp.zeros((1,))])'),
+     '            self.stats["fused_slot_rows"] += float(self.slots)',
+     '            self.stats["fused_slot_rows"] += float(self.slots)\n'
+     '            _cat = jnp.concatenate([jnp.zeros((1,)), jnp.zeros((1,))])'),
     ("JL003", "src/repro/models/inference.py",
      "        merged = jax.tree_util.tree_map_with_path(keep, new, old)",
      "        merged = new"),
     ("JL004", "src/repro/serving/sharded.py",
-     "            sampled = sample(key[0], last_logits, "
-     "temperature=temperature)",
-     "            sampled = sample(key[0], last_logits, "
-     "temperature=temperature)\n"
-     "            if lengths[0] > 0:\n"
-     "                sampled = sampled"),
+     "                    sampled = sample(key[0], last_logits,\n"
+     "                                     temperature=temperature)",
+     "                    sampled = sample(key[0], last_logits,\n"
+     "                                     temperature=temperature)\n"
+     "                    if lengths[0] > 0:\n"
+     "                        sampled = sampled"),
     ("JL005", "src/repro/serving/sharded.py",
      "            ent = self._fn_cache.get(key)",
      "            ent = self._fn_cache.get(key)\n"

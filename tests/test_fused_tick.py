@@ -13,12 +13,13 @@ dispatch depths, admission accounting approx-equal, padding rows
 BITWISE untouched. tau=0.1 per the knife-edge note (random-init gate
 scores cluster at 0.5)."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import make_cfg
+from conftest import expected_scopes, make_cfg, scopes_in
 from repro.analysis import CompileSentinel, SyncSentinel, SyncViolation
-from repro.launch.specs import extract_slot_caches
+from repro.launch.specs import alloc_batched_caches, extract_slot_caches
 from repro.models import transformer as T
 from repro.serving.backend import FusedStep, make_backend
 from repro.serving.obs import LANE_TICK, Tracer
@@ -239,6 +240,52 @@ def test_fused_phase_accounting_and_trace(served):
                for s in tracer.spans)
     assert any(s.name == "insert" and s.args.get("fused")
                for s in tracer.spans)
+
+
+def test_fused_sub_spans_nest_in_their_tick_phases(served):
+    """The engine splits the fused_step phase into feed, call and
+    bookkeeping spans and collect into its device wait; the wait is also
+    an always-on engine stat, mirrored into telemetry, that never exceeds
+    the collect phase it sits in."""
+    tracer = Tracer()
+    orch = Orchestrator(_engine(served), sched=SchedulerConfig(
+        chunk_tokens=CHUNK, dispatch_ahead=1), tracer=tracer)
+    for p in ([list(range(10, 58)), list(range(5, 41))]):
+        orch.submit(p, max_new=4)
+    orch.run()
+    tick = [s for s in tracer.spans if s.lane == (LANE_TICK, 0)]
+    parents = {"fused_feed": "fused_step", "fused_call": "fused_step",
+               "fused_bookkeep": "fused_step", "collect_wait": "collect"}
+    for child, parent in parents.items():
+        kids = [s for s in tick if s.name == child]
+        assert kids, child
+        outer = [s for s in tick if s.name == parent]
+        assert all(any(p.t0 <= k.t0 and k.t1 <= p.t1 for p in outer)
+                   for k in kids), child
+    ph = orch.telemetry.phase_times()
+    assert 0.0 < ph["collect_wait_time_s"] <= ph["collect_time_s"]
+    assert orch.telemetry.counters["collect_wait_time_s"] == \
+        ph["collect_wait_time_s"]
+
+
+@pytest.mark.parametrize("name", ["wgkv", "dense"])
+@pytest.mark.parametrize("s", [CHUNK, 1], ids=["chunk", "decode"])
+def test_fused_step_names_every_scope(served, name, s):
+    """Each piece of the compiled fused step carries its named scope in
+    the op_name metadata, in both compiled shapes, so a device trace can
+    attribute its time; a refactor that drops one fails here."""
+    eng = _engine(served, name)
+    caches = jax.eval_shape(
+        lambda: alloc_batched_caches(eng._build_empty_caches(), eng.slots))
+    b = eng.slots
+    feed = (jax.ShapeDtypeStruct((b, s), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.bool_),
+            jax.ShapeDtypeStruct((1, 2), jnp.uint32))
+    text = eng._fused.lower(eng.params, feed, caches).compile().as_text()
+    want = expected_scopes(name)
+    assert want <= scopes_in(text), want - scopes_in(text)
 
 
 def test_fused_step_is_single_device_call_kind(served):

@@ -7,10 +7,12 @@ no device work — so the tick-loop instrumentation and export contracts
 are pinned cheaply; tests/test_serving.py covers the real engines end to
 end (including ``--trace-out`` through bench_serving in CI).
 """
+import gc
 import json
 import time
 from typing import Dict
 
+import jax
 import pytest
 
 from repro.serving.backend import (BackendCapabilities, FusedStep,
@@ -21,6 +23,7 @@ from repro.serving.obs import (CAT_ENGINE, CAT_REQUEST, LANE_REQ, LANE_TICK,
                                validate_chrome_trace, write_chrome_trace)
 from repro.serving.obs.export import main as validate_cli
 from repro.serving.orchestrator.scheduler import Orchestrator, SchedulerConfig
+from repro.serving.orchestrator.session import ServeSession
 from repro.serving.orchestrator.telemetry import (PHASE_TIME_KEYS,
                                                   TELEMETRY_SCHEMA_VERSION,
                                                   Telemetry)
@@ -197,6 +200,66 @@ def test_disabled_tracer_overhead_is_noop_cheap():
         tr.add("x", 0.0, 1.0, cat=CAT_ENGINE, lane=(LANE_TICK, 0))
     t_add = time.perf_counter() - t0
     assert t_add < max(t_bare, 1e-4) * 3.0, (t_add, t_bare)
+
+
+def test_gc_span_under_enabled_tracer_only():
+    """An enabled tracer that watches the collector records each
+    collection as one ``gc`` span on the tick lane, with its generation;
+    the disabled NULL_TRACER registers no callback and records nothing."""
+    tr = Tracer(clock=FakeClock())
+    n_cb = len(gc.callbacks)
+    enabled = gc.isenabled()
+    gc.disable()                     # only the explicit collection below
+    try:
+        tr.watch_gc()
+        gc.collect()
+        tr.unwatch_gc()
+        NULL_TRACER.watch_gc()
+        assert len(gc.callbacks) == n_cb
+        gc.collect()
+    finally:
+        if enabled:
+            gc.enable()
+    spans = [s for s in tr.spans if s.name == "gc"]
+    assert len(spans) == 1
+    assert spans[0].lane == (LANE_TICK, 0) and spans[0].t1 > spans[0].t0
+    assert spans[0].args == {"generation": 2}
+    assert not NULL_TRACER.spans
+
+
+def test_session_watches_gc_while_it_holds_the_tracer():
+    n_cb = len(gc.callbacks)
+    sess = ServeSession(FakeEngine(), tracer=Tracer(clock=FakeClock()))
+    assert len(gc.callbacks) == n_cb + 1
+    sess.close()
+    assert len(gc.callbacks) == n_cb
+    ServeSession(FakeEngine()).close()       # NULL_TRACER: nothing added
+    assert len(gc.callbacks) == n_cb
+
+
+def test_tick_phases_annotate_the_device_trace_only_when_asked(monkeypatch):
+    """Under ``annotate_device`` every tick phase enters a profiler
+    annotation of its own name; the default tracer never does."""
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    _serve()
+    assert entered == []
+    assert not Tracer(enabled=False, annotate_device=True).annotate_device
+    _serve(tracer=Tracer(clock=FakeClock(), annotate_device=True))
+    assert {"memory_sample", "admit", "fused_step", "collect",
+            "evict"} <= set(entered)
 
 
 # ==========================================================================

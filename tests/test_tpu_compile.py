@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from conftest import expected_scopes, scopes_in
 from repro.configs import get_config
 from repro.kernels.paged_decode import paged_decode, paged_decode_selected
 from repro.launch.specs import alloc_batched_caches
@@ -101,7 +102,8 @@ def test_paged_decode_selected_compiles(one_chip):
 def test_fused_step_full_width_compiles(one_chip, backend, s):
     """The fused serving tick of the published qwen3-0.6b config (28
     layers, bfloat16) in both of its compiled shapes, [slots, chunk] and
-    [slots, 1], fits one 16 GB chip."""
+    [slots, 1], fits one 16 GB chip, and its operations keep the named
+    scopes a device trace attributes their time by."""
     cfg = get_config("qwen3-0.6b")
     assert cfg.dtype == "bfloat16"
     params = jax.eval_shape(lambda k: T.init_model(k, cfg),
@@ -121,3 +123,6 @@ def test_fused_step_full_width_compiles(one_chip, backend, s):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < mem.peak_memory_in_bytes and used < 16e9, mem
+    want = expected_scopes(backend)
+    found = scopes_in(compiled.as_text())
+    assert want <= found, want - found
