@@ -7,10 +7,12 @@ forced). At each position where a token was served, the *gap* is how far
 the served token's reference logit lies below the reference's best
 logit there; a sound greedy server reads a small gap, set by rounding
 (its bfloat16 against the reference's float32) and by write gates that
-sit near tau. The widest gap over the sample is held to the cell's limit
-(``bench/limits/<cell>.json``), which ``PERF.md`` derives from readings;
-so is the mean gap over the sample, which separates the served precision
-from the control's more widely than the widest gap does.
+sit near tau. The mean gap over the sample is held to the cell's limit
+(``bench/limits/<cell>.json``), which ``PERF.md`` derives from readings.
+The widest gap is reported beside it; a cell compares it only where its
+limits name it, since on the chip the control's widest gap lies within
+the reach of sound runs (a served token at a near tie) while its mean
+lies many times above theirs.
 
 ``control`` reads the same gaps for the control: the reference computed
 in the next precision below the served one (float8 e4m3 weights and

@@ -3,8 +3,10 @@ executions, each with its device seconds and the rows it carried."""
 
 
 def executions(run, prefill=None):
+    """The executions of one shape (``prefill`` True or False), or of
+    both where every shape paired with its steps (else none)."""
     fused = run.trace and run.trace.get("fused")
-    if not fused:
+    if not fused or (prefill is None and run.trace["unpaired"]):
         return []
     return [x for x in fused if prefill is None or x["prefill"] == prefill]
 

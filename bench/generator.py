@@ -11,8 +11,8 @@ A mix file (``bench/traffic/<mix>.json``) states
   "hi"}`` or ``{"dist": "lognormal", "median", "sigma", "lo", "hi"}``
   (clipped to ``[lo, hi]``);
 * ``round``: how many requests share one set of lengths (below);
-* ``trace``: the stretch of the window a ``--trace 1`` run records
-  (``lead_s`` into it, for ``seconds``; read by ``bench/harness.py``);
+* ``trace``: the stretch of the window a ``--trace 1`` run records (its
+  last ``seconds``; read by ``bench/harness.py``);
 * ``who``: which users send such traffic.
 
 Every seed gets the same set of sizes and arrivals in another order:

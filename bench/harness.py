@@ -25,11 +25,13 @@ import numpy as np
 from bench import check, generator, spec
 from bench.peaks import peaks_for
 
-# traced runs: the profiler records ``seconds`` of the window from
-# ``lead_s`` into it, as the mix's ``trace`` entry says (a stretch that
-# holds the steps the cell's per-layer metrics read, and a trace small
-# enough to read within the run's time limit)
-TRACE_DEFAULT = {"lead_s": 10.0, "seconds": 6.0}
+# traced runs: the profiler records the window's last ``seconds``, as the
+# mix's ``trace`` entry says (a stretch that holds the steps the cell's
+# per-layer metrics read, and a trace small enough to read within the
+# run's time limit). It stops, and its trace is read, after the window
+# has closed: stopping takes the host many seconds, in which nothing
+# would be served.
+TRACE_DEFAULT = {"seconds": 6.0}
 
 
 class DeviceError(RuntimeError):
@@ -235,8 +237,7 @@ def run_cell(bm: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
     pending = None if closed else next(gen)
     traced = None
     tw = dict(TRACE_DEFAULT, **mix.get("trace", {}))
-    t_trace0 = t_open + min(tw["lead_s"], 0.3 * seconds)
-    t_trace1 = min(t_end, t_trace0 + tw["seconds"])
+    t_trace0 = max(t_open, t_end - tw["seconds"])
 
     while True:
         now = time.perf_counter()
@@ -244,8 +245,6 @@ def run_cell(bm: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
             break
         if trace and traced is None and now >= t_trace0:
             traced = _start_trace(jax, eng, steplog, trace_dir)
-        if traced is not None and not traced.get("t1") and now >= t_trace1:
-            _stop_trace(jax, eng, steplog, traced)
         if closed:
             while finished:
                 finished.pop()
@@ -260,7 +259,7 @@ def run_cell(bm: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
             # idle open loop: wait for the next arrival
             wait = min(t_open + pending.due_s, t_end)
             time.sleep(max(0.0, wait - time.perf_counter()))
-    if traced is not None and not traced.get("t1"):
+    if traced is not None:
         _stop_trace(jax, eng, steplog, traced)
     window_compiles = compiles.n - c0
     tel1 = dict(sess.telemetry.counters)
@@ -289,6 +288,12 @@ def run_cell(bm: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
               "ttft_p50_second_half_s": statistics.median(halves[1])
               if halves[1] else None,
               "compiles_in_window": window_compiles}
+    if traced is not None:
+        # the profiler's start and stop calls, in seconds from the open
+        window["traced_s"] = [traced["t0"] - t_open, traced["t1"] - t_open]
+        log(f"trace: from {window['traced_s'][0]:.2f} s into the window "
+            f"to {window['traced_s'][1]:.2f} s; stopping the profiler "
+            f"took {traced['stop_s']:.2f} s")
     log(f"window: {seconds:.0f} s, {attempted} requests sent, "
         f"{len(done)} finished, {len(ttft)} first tokens, {n_out} tokens, "
         f"{len(gaps)} inter-token gaps, {backlog} sent without a first "
@@ -307,13 +312,15 @@ def run_cell(bm: Dict, cell: Dict, seed: int, seconds: float, trace: bool,
 
     run = None
     if trace:
-        from bench import tracing
         red = None
         if traced:
-            red = tracing.reduce_run(traced, tracer, fam.FUSED_PROGRAM)
+            t_red = time.perf_counter()
+            red = reduce_trace(traced, tracer, eng, fam, e["chunk_tokens"])
             shutil.rmtree(trace_dir, ignore_errors=True)
-            log(f"trace: {red and red['fused_executions']} fused-step "
-                f"executions, {red and red['steps_dispatched']} dispatched")
+            log(f"trace: fused-step executions and steps dispatched by "
+                f"shape {red and red['shape_counts']}, unpaired "
+                f"{red and red['unpaired']}; read in "
+                f"{time.perf_counter() - t_red:.1f} s")
         run = Run(cfg=cfg, family=fam, peaks=peaks,
                   ticks=tel1.get("ticks", 0) - tel0.get("ticks", 0),
                   phases={k: tel1.get(k, 0.0) - tel0.get(k, 0.0)
@@ -368,7 +375,8 @@ def _start_trace(jax, eng, steplog, trace_dir: str) -> Dict:
     t0_mono = time.monotonic()
     ann.__enter__()
     steplog.tracing = True
-    return {"dir": trace_dir, "ann": ann, "t0_mono": t0_mono}
+    return {"dir": trace_dir, "ann": ann, "t0_mono": t0_mono,
+            "t0": time.perf_counter()}
 
 
 def _stop_trace(jax, eng, steplog, traced: Dict) -> None:
@@ -377,4 +385,26 @@ def _stop_trace(jax, eng, steplog, traced: Dict) -> None:
     traced["ann"].__exit__(None, None, None)
     traced["t1"] = time.perf_counter()
     jax.profiler.stop_trace()
+    traced["stop_s"] = time.perf_counter() - traced["t1"]
     traced["steplog"] = steplog
+
+
+def reduce_trace(traced: Dict, tracer, eng, fam, chunk: int
+                 ) -> Optional[Dict]:
+    """The traced window's reduction (``bench/tracing.py``), with the
+    fused step's device time by named scope (``bench/scopes.py``) under
+    ``scopes`` and its ten largest scopes in the breakdown."""
+    from bench import scopes, tracing
+    ev = tracing.load(traced["dir"])
+    red = tracing.reduce_run(ev, traced, tracer, fam.FUSED_PROGRAM)
+    if red is None:
+        return None
+    names: Dict[str, str] = {}
+    for text in scopes.fused_hlo_texts(eng, chunk):
+        names.update(scopes.op_names(text))
+    sc = scopes.reduce(ev, fam.FUSED_PROGRAM, names, red["steps"],
+                       scopes.tick_span_names(tracer))
+    red["scopes"] = sc
+    red["breakdown"]["device_scopes"] = sorted(
+        ([k, v] for k, v in sc["scopes"].items()), key=lambda kv: -kv[1])[:10]
+    return red

@@ -15,6 +15,8 @@ text. The reduction takes, on the trace's own clock:
   less that of the operations nested in it, so a ``while`` does not
   count its body again), each under its innermost scope;
 * ``fused_leaf_s``: the total of those seconds, per shape and in all;
+* ``shares``: the :data:`SHARES` metrics, percent of those seconds, which
+  a traced run reports as per-layer metrics (``bench/metrics/``);
 * ``idle_gaps``: the longest idle stretches of the device, each labelled
   by the program's annotation that covers it most (the innermost on a
   tie), among the names its tick-lane spans use; the annotations are
@@ -106,14 +108,6 @@ def self_times(ops: Sequence[Dict]) -> List[Tuple[Dict, float]]:
     return out
 
 
-def executions(ev: Dict, program: str, w: Tuple[float, float]) -> List[Dict]:
-    """The fused program's executions inside the window, in order."""
-    return sorted((e for e in ev["modules"]
-                   if e["name"].split("(")[0] == program
-                   and e["t0"] >= w[0] and e["t1"] <= w[1]),
-                  key=lambda e: e["t0"])
-
-
 def scope_seconds(ev: Dict, program: str, w: Tuple[float, float],
                   names: Dict[str, str],
                   steps: Optional[List[Dict]] = None) -> Dict:
@@ -121,14 +115,14 @@ def scope_seconds(ev: Dict, program: str, w: Tuple[float, float],
 
     ``names`` maps an HLO op to its op_name (:func:`op_names`). ``steps``
     (the steps dispatched while tracing, in order, each with ``prefill``)
-    gives each execution its shape where they pair one to one; otherwise
-    executions are told apart by their program name."""
-    execs = executions(ev, program, w)
-    paired = steps is not None and len(steps) == len(execs)
-    shape_of = {}
-    for i, x in enumerate(execs):
-        shape_of[id(x)] = (("prefill" if steps[i]["prefill"] else "decode")
-                           if paired else x["name"])
+    gives each execution its shape (``tracing.by_shape``); where it
+    cannot, executions are told apart by their program name."""
+    execs = tracing.executions(ev, program, w)
+    by = tracing.by_shape(execs, steps or [])
+    paired = bool(by) and all(len(xs) == len(ss) for xs, ss in by.values())
+    shape_of = {id(x): shape for shape, (xs, _) in by.items() for x in xs}
+    for x in execs:
+        shape_of.setdefault(id(x), x["name"])
     by_plane: Dict[str, List[Dict]] = {}
     for x in execs:
         by_plane.setdefault(x["plane"], []).append(x)
@@ -265,26 +259,31 @@ def fused_hlo_texts(eng, chunk: int) -> List[str]:
     return out
 
 
+def share(run, metric: str) -> Optional[float]:
+    """A :data:`SHARES` metric of a traced run (``harness.Run``): None
+    where the run holds no scope reduction or no fused-step time."""
+    sc = run.trace and run.trace.get("scopes")
+    return sc["shares"][metric] if sc else None
+
+
 def main(argv=None) -> int:
     """Run one cell's traced run and print this reduction of its trace.
 
-    The harness reduces its trace with ``bench/tracing.py`` and then
-    deletes it; this wraps that call (and the warm-up, to reach the
-    engine) for the one run, so the same trace is reduced here too."""
+    The harness reduces its trace (``harness.reduce_trace``) and then
+    deletes it; this keeps the whole scope reduction of that run, and the
+    count and seconds of each tick-lane span, and prints them beside the
+    run's result."""
     import argparse
     import json
     import time
-    import traceback
 
     t_start = time.perf_counter()
     ap = argparse.ArgumentParser(description=main.__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--lead-s", type=float, default=None,
-                    help="trace from this far into the window")
     ap.add_argument("--trace-s", type=float, default=None,
-                    help="trace this long")
+                    help="trace the window's last this many seconds")
     args = ap.parse_args(argv)
 
     import jax
@@ -295,38 +294,15 @@ def main(argv=None) -> int:
     cell = spec.cell(bm, args.workload)
     harness.check_devices(jax.devices(), cell["chips"])
     run.configure_jax(jax)
-    cfg = spec.config(bm, cell["config"])
     mix = spec.traffic(cell["traffic"])
-    tw = dict(mix.get("trace", {}))
-    if args.lead_s is not None:
-        tw["lead_s"] = args.lead_s
     if args.trace_s is not None:
-        tw["seconds"] = args.trace_s
-    mix["trace"] = tw
+        mix["trace"] = dict(mix.get("trace", {}), seconds=args.trace_s)
     found: Dict = {}
-    warm_up, reduce_run = harness.warm_up, tracing.reduce_run
+    reduce_trace = harness.reduce_trace
 
-    def warm_up_(eng, *a, **kw):
-        found["eng"] = eng
-        return warm_up(eng, *a, **kw)
-
-    def reduce_run_(traced, tracer, program):
-        red = reduce_run(traced, tracer, program)
-        try:
-            _reduce_here(traced, tracer, program)
-        except Exception:            # the run's own result still stands
-            found["error"] = traceback.format_exc()
-        return red
-
-    def _reduce_here(traced, tracer, program):
-        ev = tracing.load(traced["dir"])
-        names = {}
-        for text in fused_hlo_texts(found["eng"],
-                                    cfg["engine"]["chunk_tokens"]):
-            names.update(op_names(text))
-        steps = [s for s in traced["steplog"].steps if s["traced"]]
-        found["scopes"] = reduce(ev, program, names, steps,
-                                 tick_span_names(tracer))
+    def reduce_trace_(traced, tracer, *a):
+        red = reduce_trace(traced, tracer, *a)
+        found["scopes"] = red and red["scopes"]
         spans: Dict[str, List[float]] = {}
         for sp in tracer.spans:
             if sp.lane[0] == "tick":
@@ -334,16 +310,16 @@ def main(argv=None) -> int:
                 n_s[0] += 1
                 n_s[1] += sp.t1 - sp.t0
         found["tick_spans"] = spans
+        return red
 
-    harness.warm_up, tracing.reduce_run = warm_up_, reduce_run_
+    harness.reduce_trace = reduce_trace_
     try:
         result = harness.run_cell(bm, cell, args.seed, args.seconds, True,
                                   t_start=t_start, trace_dir=run.TRACE_DIR,
                                   mix=mix)
     finally:
-        harness.warm_up, tracing.reduce_run = warm_up, reduce_run
+        harness.reduce_trace = reduce_trace
     print(json.dumps({"workload": args.workload, "seed": args.seed,
-                      "error": found.get("error"),
                       "device_scopes": found.get("scopes"),
                       # count and seconds of each tick-lane span, whole run
                       "tick_spans": found.get("tick_spans"),

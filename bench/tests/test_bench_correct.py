@@ -26,10 +26,10 @@ def tiny():
         return json.load(f)
 
 
-def run(fault=None, control=False, seed=2**31 + 11):
+def run(fault=None, control=False, seed=2**31 + 11, limits=LIMITS):
     return harness.run_cell(spec.load_benchmark(), CELL, seed, 2.0, False,
                             t_start=time.perf_counter(), cfg=tiny(),
-                            mix=MIX, limits=LIMITS, fault=fault,
+                            mix=MIX, limits=limits, fault=fault,
                             control=control)
 
 
@@ -74,6 +74,18 @@ def test_broken_timed_path_is_not_correct(fault):
     res = run(fault=fault)
     assert not res["correct"]
     assert res["checks"]["max_logit_gap"]["value"] > LIMITS["max_logit_gap"]
+
+
+@pytest.mark.parametrize("fault", [token_altered, state_unchanged],
+                         ids=["token_altered", "state_unchanged"])
+def test_broken_timed_path_fails_the_mean_alone(fault):
+    """The cells compare the mean gap and the tokens compared, not the
+    widest gap: each fault is caught by those alone."""
+    lims = {k: v for k, v in LIMITS.items() if k != "max_logit_gap"}
+    res = run(fault=fault, limits=lims)
+    assert not res["correct"]
+    assert res["checks"]["max_logit_gap"]["limit"] is None
+    assert res["checks"]["mean_logit_gap"]["value"] > lims["mean_logit_gap"]
 
 
 def test_reference_matches_program_forward():

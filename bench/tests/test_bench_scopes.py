@@ -171,11 +171,51 @@ def excerpt():
 def test_chip_excerpt_pairs_executions_with_dispatched_steps():
     d, ev = excerpt()
     red = tracing.reduce(ev, d["program"], d["steps"], [])
-    assert red["fused_executions"] == len(d["steps"]) > 0
-    assert red["fused"] is not None
+    n = len(d["steps"])
+    assert red["fused_executions"] == n == 3
+    assert red["shape_counts"] == {"decode": [n, n]}
+    assert red["fused"] is not None and red["unpaired"] == []
     sc = scopes.scope_seconds(ev, d["program"], scopes.window(ev),
                               d["op_names"], d["steps"])
     assert sc["paired"]
+
+
+def test_chip_excerpt_with_one_execution_lost():
+    """With the last execution's events lost, its shape reports its
+    counts and no duration, and its executions still count by scope."""
+    d, ev = excerpt()
+    last = max((m for m in ev["modules"]
+                if m["name"].startswith(d["program"])),
+               key=lambda m: m["t0"])
+    ev["modules"].remove(last)
+    n = len(d["steps"])
+    red = tracing.reduce(ev, d["program"], d["steps"], [])
+    assert red["fused_executions"] == n - 1
+    assert red["shape_counts"] == {"decode": [n - 1, n]}
+    assert red["fused"] is None and red["unpaired"] == ["decode"]
+    sc = scopes.scope_seconds(ev, d["program"], scopes.window(ev),
+                              d["op_names"], d["steps"])
+    assert not sc["paired"]
+    assert set(sc["shapes"]) == {"decode"}
+
+
+@pytest.mark.parametrize("metric", sorted(scopes.SHARES))
+def test_share_readers_on_the_chip_excerpt(metric):
+    """Each share's reader reads the scope reduction of the traced
+    window, as the harness keeps it, and nothing without one."""
+    import importlib
+    d, ev = excerpt()
+    red = tracing.reduce(ev, d["program"], d["steps"], [])
+    red["scopes"] = scopes.reduce(ev, d["program"], d["op_names"],
+                                  d["steps"], d["tick_names"])
+    read = importlib.import_module(f"bench.metrics.{metric}").read
+    got = read(types.SimpleNamespace(trace=red))
+    assert got == red["scopes"]["shares"][metric]
+    assert 0.0 < got < 100.0
+    if metric == "cache_rewrite_pct":
+        # a decode step on the chip: row_keep and layer_scan, two thirds
+        assert 60.0 < got < 72.0
+    assert read(types.SimpleNamespace(trace=None)) is None
 
 
 def test_chip_excerpt_leaf_time_is_under_named_scopes():
@@ -188,6 +228,6 @@ def test_chip_excerpt_leaf_time_is_under_named_scopes():
         - sc["scopes"].get(scopes.UNSCOPED, 0.0)
     assert named >= 0.95 * total > 0
     # the executions' leaf time adds up to their device time
-    execs = scopes.executions(ev, d["program"], scopes.window(ev))
+    execs = tracing.executions(ev, d["program"], scopes.window(ev))
     busy = sum(x["t1"] - x["t0"] for x in execs[:d["op_executions"]])
     assert total == pytest.approx(busy, rel=0.01)

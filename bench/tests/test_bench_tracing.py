@@ -2,6 +2,7 @@
 dispatched steps, idle gaps labelled by the host's tick phase."""
 import json
 import os
+import types
 
 import pytest
 
@@ -55,6 +56,57 @@ def test_reduce_synthetic():
     assert red["breakdown"]["device_ops"][0][0] in ("fusion.1", "while.2")
 
 
-def test_reduce_refuses_to_pair_unequal_lists():
+def shaped(lost):
+    """A window of five fused steps, prefill-shape (program ``jit_fn(7)``,
+    3 s) and decode-only (``jit_fn(9)``, 1 s) in turn, with the
+    executions at the indices ``lost`` missing from the trace; and the
+    steps the step log saw dispatched."""
+    kinds = [True, False, True, False, False]
+    host = [{"name": tracing.WINDOW, "t0": 0.0, "t1": 40.0, "plane": "h"}]
+    mods, t = [], 1.0
+    for i, pre in enumerate(kinds):
+        d = 3.0 if pre else 1.0
+        if i not in lost:
+            mods.append({"name": "jit_fn(7)" if pre else "jit_fn(9)",
+                         "t0": t, "t1": t + d, "plane": "/device:0"})
+        t += d + 1.0
+    steps = [{"prefill": pre, "rows": []} for pre in kinds]
+    return {"host": host, "modules": mods, "ops": []}, steps
+
+
+@pytest.mark.parametrize("lost,durations,unpaired", [
+    # a lost decode execution: the prefill shape keeps its durations
+    ((3,), {True: [3.0, 3.0]}, ["decode"]),
+    # a loss in both shapes: no durations
+    ((0, 4), {}, ["prefill", "decode"]),
+], ids=["decode_lost", "both_lost"])
+def test_reduce_refuses_to_pair_unequal_lists(lost, durations, unpaired):
+    from bench.metrics import fused_step_roofline, step_decode_ms, \
+        step_prefill_ms
+    ev, steps = shaped(lost)
+    red = tracing.reduce(ev, "jit_fn", steps, [])
+    assert red["unpaired"] == unpaired
+    assert red["fused_executions"] == 5 - len(lost)
+    got = {}
+    for f in red["fused"] or []:
+        got.setdefault(f["prefill"], []).append(f["dur_s"])
+    assert got == durations
+    run = types.SimpleNamespace(trace=red)
+    pre = step_prefill_ms.read(run)
+    assert pre == (pytest.approx(3000.0) if True in durations else None)
+    assert step_decode_ms.read(run) is None
+    # the whole step's roofline needs every shape paired
+    assert fused_step_roofline.read(run) is None
+
+
+def test_reduce_pairs_within_each_shape():
+    ev, steps = shaped(())
+    red = tracing.reduce(ev, "jit_fn", steps, [])
+    assert red["unpaired"] == []
+    assert red["shape_counts"] == {"prefill": [2, 2], "decode": [3, 3]}
+    assert [(f["prefill"], f["dur_s"]) for f in red["fused"]] == [
+        (True, 3.0), (False, 1.0), (True, 3.0), (False, 1.0), (False, 1.0)]
+    # programs that do not match the step log's shapes in number pair
+    # with nothing
     red = tracing.reduce(synthetic(), "jit_fn", [{"prefill": True}], [])
     assert red["fused"] is None and red["fused_executions"] == 2

@@ -8,8 +8,10 @@ reduction takes, all on the trace's own clock:
   ran (``XLA Ops`` lines of the device planes), inside the window;
 * ``fused``: each execution of the fused step's program (``XLA Modules``
   line, by the program's name), paired in order with the steps the
-  harness saw dispatched while tracing (the profiler starts and stops
-  with nothing in flight, so both lists hold the same steps);
+  harness saw dispatched while tracing, within each of the step's two
+  shapes (the profiler starts and stops with nothing in flight, so both
+  lists hold the same steps, unless the profiler lost an execution's
+  events: then that shape alone goes unpaired);
 * ``breakdown``: the device operations that took most time, and the
   longest idle gaps, each labelled by the serving loop's tick phase
   that was open on the host during it (the program's own span tracer,
@@ -19,9 +21,12 @@ from __future__ import annotations
 
 import glob
 import os
+import statistics
 from typing import Dict, List, Optional, Sequence, Tuple
 
 WINDOW = "bench_trace_window"
+# the fused step's two shapes, as the step log tells them apart
+SHAPES = ("prefill", "decode")
 Interval = Tuple[float, float]
 
 
@@ -92,6 +97,40 @@ def label(gap: Interval, spans: Sequence[Tuple[str, float, float]]) -> str:
     return name
 
 
+def executions(ev: Dict[str, List[Dict]], program: str,
+               w: Interval) -> List[Dict]:
+    """The fused program's executions inside the window, in order."""
+    return sorted((e for e in ev["modules"]
+                   if e["name"].split("(")[0] == program
+                   and e["t0"] >= w[0] and e["t1"] <= w[1]),
+                  key=lambda e: e["t0"])
+
+
+def by_shape(execs: List[Dict], steps: List[Dict]) -> Dict[str, Tuple]:
+    """The fused step's executions and the steps dispatched, split by the
+    step's shape: ``{"prefill": (executions, steps), "decode": (...)}``.
+
+    A step's shape is its ``prefill`` flag (a ``[slots, chunk]`` step
+    carried a prompt chunk, a ``[slots, 1]`` one did not). An execution's
+    is its program: each shape is a program of its own, whose name
+    carries the compiled program's fingerprint. The programs, ranked by
+    their median duration, take the shapes present in the steps in the
+    order prefill, decode: a chunk of positions takes longer than one.
+    Where programs and shapes differ in number, no execution is given a
+    shape."""
+    shapes = {k: [s for s in steps if s["prefill"] == (k == "prefill")]
+              for k in SHAPES}
+    shapes = {k: ss for k, ss in shapes.items() if ss}
+    progs: Dict[str, List[Dict]] = {}
+    for e in execs:
+        progs.setdefault(e["name"], []).append(e)
+    ranked = sorted(progs.values(), key=lambda xs: -statistics.median(
+        x["t1"] - x["t0"] for x in xs))
+    if len(ranked) != len(shapes):
+        ranked = [[] for _ in shapes]
+    return {k: (xs, shapes[k]) for k, xs in zip(shapes, ranked)}
+
+
 def reduce(ev: Dict[str, List[Dict]], program: str, steps: List[Dict],
            spans: Sequence[Tuple[str, float, float]]) -> Optional[Dict]:
     """``ev`` as :func:`load` returns it; ``program`` the fused step's
@@ -114,27 +153,32 @@ def reduce(ev: Dict[str, List[Dict]], program: str, steps: List[Dict],
     top_ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:10]
     idle = gaps(busy_by_dev[devices[0]], w)
     top_gaps = sorted(idle, key=lambda g: g[0] - g[1])[:10]
-    execs = sorted((e for e in ev["modules"]
-                    if e["name"].split("(")[0] == program
-                    and e["t0"] >= w[0] and e["t1"] <= w[1]),
-                   key=lambda e: e["t0"])
-    fused = None
-    if execs and len(execs) == len(steps):
-        fused = [dict(s, dur_s=e["t1"] - e["t0"])
-                 for e, s in zip(execs, steps)]
-    return {"busy_s": busy_s, "window_s": w[1] - w[0], "fused": fused,
-            "fused_executions": len(execs), "steps_dispatched": len(steps),
+    execs = executions(ev, program, w)
+    # within a shape, executions and steps pair in order where their
+    # counts agree; a shape whose counts differ gives no duration
+    paired, counts, unpaired = [], {}, []
+    for shape, (xs, ss) in by_shape(execs, steps).items():
+        counts[shape] = [len(xs), len(ss)]
+        if len(xs) == len(ss):
+            paired += [(x["t0"], dict(s, dur_s=x["t1"] - x["t0"]))
+                       for x, s in zip(xs, ss)]
+        else:
+            unpaired.append(shape)
+    fused = [f for _, f in sorted(paired, key=lambda p: p[0])]
+    return {"busy_s": busy_s, "window_s": w[1] - w[0],
+            "fused": fused or None, "unpaired": unpaired, "steps": steps,
+            "fused_executions": len(execs), "shape_counts": counts,
             "breakdown": {
                 "device_ops": [[n, s] for n, s in top_ops],
                 "idle_gaps": [[label(g, spans), g[1] - g[0]]
                               for g in top_gaps]}}
 
 
-def reduce_run(traced: Dict, tracer, program: str) -> Optional[Dict]:
-    """Reduce a harness-traced run: its trace directory, the steps its
-    step log saw dispatched while tracing, and the program's tick-phase
-    spans shifted onto the trace's clock."""
-    ev = load(traced["dir"])
+def reduce_run(ev: Dict[str, List[Dict]], traced: Dict, tracer,
+               program: str) -> Optional[Dict]:
+    """Reduce a harness-traced run: its trace (as :func:`load` returns
+    it), the steps its step log saw dispatched while tracing, and the
+    program's tick-phase spans shifted onto the trace's clock."""
     steps = [s for s in traced["steplog"].steps if s["traced"]]
     win = [e for e in ev["host"] if e["name"] == WINDOW]
     shift = (win[0]["t0"] - traced["t0_mono"]) if win else 0.0
