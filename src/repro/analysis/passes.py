@@ -13,8 +13,10 @@ JL001  host sync in a hot-path function (``jax.device_get`` / ``.item()`` /
        values) without ``allow-sync(reason)``
 JL002  ``jnp.concatenate``/``jnp.stack`` in a sharded code path — the
        PR 3/5 XLA-CPU SPMD mixed-tiling-concat miscompute class
-JL003  cache state escaping a masked scan body without routing through the
-       per-leaf masked select (``tree_map`` + ``jnp.where``)
+JL003  cache state escaping a masked scan body without a row mask: neither
+       routed through a masked select (``tree_map`` + ``jnp.where``) nor
+       produced by a call given the row mask at its writes
+       (``decode_step(..., active=...)``)
 JL004  Python ``if``/``while``/``assert`` on a traced value inside a jitted
        function
 JL005  ``jax.jit`` call in the tick path without a declared shape budget
@@ -51,7 +53,7 @@ TICK_PATH_MODULES: Tuple[str, ...] = (
 )
 
 # Calls whose outputs count as already-masked cache state for JL003: the
-# per-leaf select itself, and the ragged extend whose body performs it.
+# per-leaf select itself, and the ragged extend whose body masks its rows.
 MASKED_PRODUCERS: Tuple[str, ...] = (
     "tree_map",
     "tree_map_with_path",
@@ -59,6 +61,15 @@ MASKED_PRODUCERS: Tuple[str, ...] = (
     "select",
     "prefill_extend_ragged",
 )
+
+# Calls whose outputs count as masked only when given the row mask as the
+# ``ROW_MASK_KEYWORD`` argument: they mask rows at each write they make
+# (the decode step, and the stacked dual-cache write it ends in).
+ROW_MASKED_PRODUCERS: Tuple[str, ...] = (
+    "decode_step",
+    "lazy_promote_and_write",
+)
+ROW_MASK_KEYWORD = "active"
 
 # Parameter names that seed JL003's cache-flow tracking.
 CACHE_PARAM_NAMES: FrozenSet[str] = frozenset(
@@ -411,7 +422,12 @@ _PLAIN, _CACHE, _RAW, _MASKED = "plain", "cache", "raw", "masked"
 def _is_masked_producer(call: ast.Call) -> bool:
     d = _dotted(call.func) or ""
     last = d.rsplit(".", 1)[-1]
-    return last in MASKED_PRODUCERS
+    if last in MASKED_PRODUCERS:
+        return True
+    return last in ROW_MASKED_PRODUCERS and any(
+        kw.arg == ROW_MASK_KEYWORD
+        and not (isinstance(kw.value, ast.Constant) and kw.value.value is None)
+        for kw in call.keywords)
 
 
 def check_masked_scan_body(ctx: ModuleContext) -> List[Finding]:
@@ -506,9 +522,11 @@ def check_masked_scan_body(ctx: ModuleContext) -> List[Finding]:
                     "JL003",
                     node.lineno,
                     f"cache state {raw} escapes the masked scan body without "
-                    "routing through the per-leaf masked select "
-                    "(tree_map + jnp.where over the pre-step tree) — "
-                    "short/padding rows will see unmasked writes",
+                    "a row mask: route it through the per-leaf masked "
+                    "select (tree_map + jnp.where over the pre-step tree) "
+                    "or produce it with the row mask at the write "
+                    "(decode_step(..., active=...)) — short/padding rows "
+                    "will see unmasked writes",
                 )
             )
     return out

@@ -15,15 +15,19 @@ and are what the composable SnapKV eviction (core/eviction.py) relieves.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.admission import select_global
 from repro.core.selection import (
-    build_page_meta, init_page_meta, update_page_meta_on_write,
+    META_BIG, PAGE_SIZE, build_page_meta, init_page_meta,
+    update_page_meta_on_write,
 )
+from repro.kernels.cache_write import dual_cache_write
+from repro.kernels.ops import interpret_mode
 
 
 class DualCache(NamedTuple):
@@ -137,6 +141,24 @@ def prefill_populate(
     )
 
 
+def layer_of(stack, layer):
+    """Layer ``layer`` of a stacked cache pytree (``[L, B, ...]`` leaves)."""
+    return jax.tree.map(lambda a: a[layer], stack)
+
+
+def take_at(a: jax.Array, idx: Tuple[jax.Array, ...]) -> jax.Array:
+    """``a[idx]`` at distinct indices known to lie in bounds (no clamp)."""
+    return a.at[idx].get(mode="promise_in_bounds", unique_indices=True)
+
+
+def put_at(a: jax.Array, idx: Tuple[jax.Array, ...], val: jax.Array
+           ) -> jax.Array:
+    """``a[idx] = val`` in place at distinct indices; an index past its
+    axis drops that update, which is how a masked write keeps what the
+    slot held without reading it."""
+    return a.at[idx].set(val, mode="drop", unique_indices=True)
+
+
 def lazy_promote_and_write(
     cache: DualCache,
     k_new: jax.Array,  # [B, H, hd] post-RoPE key of the freshly generated token
@@ -144,58 +166,124 @@ def lazy_promote_and_write(
     g_new: jax.Array,  # [B, H]
     *,
     tau: float,
+    layer: Optional[jax.Array] = None,
+    active: Optional[jax.Array] = None,
+    kernel: bool = False,
 ) -> DualCache:
     """Decode-phase cache update (paper Fig. 6d):
 
     1. inspect the victim at the ring pointer;
     2. promote it to the Global Cache iff its stored g >= tau (per head);
     3. overwrite the slot with the new token; advance the pointer.
+
+    ``cache`` is one layer (``[B, ...]`` leaves) or, with ``layer`` set, a
+    stack of layers (``[L, B, ...]`` leaves) written at index ``layer`` in
+    place; the one-layer form is the stacked form with L = 1. Only the
+    slots a position touches change: the victim's global slot, its page's
+    metadata and the ring slot at the pointer. ``active`` [B] bool
+    masks rows at the write: an inactive row's writes are dropped and its
+    counters kept, so its state stays bit-identical.
+
+    ``kernel``: write with the Pallas kernel (``kernels/cache_write.py``,
+    one custom call a layer in place of some forty small scatters,
+    gathers and selects). The caller sets it for a program on one TPU
+    device; the jnp scatters below are what the SPMD partitioner splits
+    over a mesh and what the CPU runs fast. The two are bit-identical.
     """
+    if layer is None:
+        out = lazy_promote_and_write(
+            jax.tree.map(lambda a: a[None], cache), k_new, v_new, g_new,
+            tau=tau, layer=jnp.zeros((), jnp.int32), active=active,
+            kernel=kernel)
+        return layer_of(out, 0)
     with jax.named_scope("cache_write"):
-        b, h, w, d = cache.lk.shape
-        c = cache.budget
-        barange = jnp.arange(b)
+        _, b, h, w, d = cache.lk.shape
+        c = cache.gk.shape[3]
+        if active is None:
+            active = jnp.ones((b,), bool)
+        li = layer.astype(jnp.int32)
+        ptr, t, gcnt = cache.ptr[li], cache.t[li], cache.gcnt[li]
+        if kernel:
+            return _kernel_write(cache, k_new, v_new, g_new, tau=tau, li=li,
+                                 active=active, ptr=ptr, t=t, gcnt=gcnt)
+        bi = jnp.broadcast_to(jnp.arange(b)[:, None], (b, h))
+        hi = jnp.broadcast_to(jnp.arange(h)[None, :], (b, h))
+        # per-head indices on every axis but hd, as for the global scatter
+        # below: the ring keeps its layout across the write
+        at_ring = jnp.broadcast_to(ptr[:, None], (b, h))
         # ---- victim ------------------------------------------------------
-        vk = cache.lk[barange, :, cache.ptr]              # [B, H, hd]
-        vv = cache.lv[barange, :, cache.ptr]
-        vg = cache.lg[barange, :, cache.ptr]              # [B, H]
-        vpos = cache.lpos[barange, cache.ptr]             # [B]
-        victim_valid = vpos >= 0                          # [B]
+        vk = take_at(cache.lk, (li, bi, hi, at_ring))     # [B, H, hd]
+        vv = take_at(cache.lv, (li, bi, hi, at_ring))
+        # the small per-slot leaves (lg, lpos, gpos: no hd axis) are read
+        # and written through a one-hot select over the layer's slice
+        # (<= 128 KB a layer): a scatter of single elements along their
+        # minor axis makes XLA's TPU backend move the whole stacked leaf
+        # through on-chip memory at every layer
+        at_ptr = jnp.arange(w)[None, :] == ptr[:, None]   # [B, W]
+        lg_l, lpos_l = cache.lg[li], cache.lpos[li]
+        vg = jnp.where(at_ptr[:, None], lg_l, 0.0).sum(-1)  # [B, H]
+        vpos = jnp.where(at_ptr, lpos_l, 0).sum(-1)       # [B]
+        victim_valid = (vpos >= 0) & active               # [B]
         promote = victim_valid[:, None] & (vg >= tau)     # [B, H]
-        can_write = promote & (cache.gcnt < c)
+        can_write = promote & (gcnt < c)
         # ---- promotion: true scatter (touches one slot per head, not the
         # whole cache — the jnp analogue of the paged in-place page write;
         # §Perf P3 iteration: the previous one-hot `where` rewrote the entire
-        # global cache every step, tripling decode HBM traffic) ----------
-        dest = jnp.minimum(cache.gcnt, c - 1)             # [B, H]
-        bi = barange[:, None].repeat(h, 1)                # [B, H]
-        hi = jnp.arange(h)[None, :].repeat(b, 0)
-        old_k = cache.gk[bi, hi, dest]
-        old_v = cache.gv[bi, hi, dest]
-        old_p = cache.gpos[bi, hi, dest]
-        up_k = jnp.where(can_write[..., None], vk.astype(cache.gk.dtype), old_k)
-        up_v = jnp.where(can_write[..., None], vv.astype(cache.gv.dtype), old_v)
-        up_p = jnp.where(can_write, vpos[:, None], old_p)
-        gk = cache.gk.at[bi, hi, dest].set(up_k)
-        gv = cache.gv.at[bi, hi, dest].set(up_v)
-        gpos = cache.gpos.at[bi, hi, dest].set(up_p)
-        gcnt = cache.gcnt + can_write.astype(jnp.int32)
-        overflow = cache.overflow + (promote & ~can_write).astype(jnp.int32)
+        # global cache every step, tripling decode HBM traffic); a head
+        # that does not promote aims past the budget and is dropped -------
+        dest = jnp.minimum(gcnt, c - 1)                   # [B, H]
+        at_dest = jnp.arange(c) == dest[..., None]        # [B, H, C]
+        gpos_l = cache.gpos[li]
+        to = (li, bi, hi, jnp.where(can_write, dest, c))
+        gk = put_at(cache.gk, to, vk.astype(cache.gk.dtype))
+        gv = put_at(cache.gv, to, vv.astype(cache.gv.dtype))
+        gpos = cache.gpos.at[li].set(jnp.where(
+            at_dest & can_write[..., None], vpos[:, None, None], gpos_l))
+        gcnt = cache.gcnt.at[li].set(gcnt + can_write.astype(jnp.int32))
+        overflow = cache.overflow.at[li].add(
+            (promote & ~can_write).astype(jnp.int32))
         # incremental Quest metadata: fold the promoted key into the one page
         # its append lands in (same touched-slot discipline as the gk scatter)
         pkmin, pkmax = update_page_meta_on_write(
-            cache.pkmin, cache.pkmax, dest, vk, can_write)
-        # ---- write the new token into the ring (scatter at ptr) ----------
-        lk = cache.lk.at[barange, :, cache.ptr].set(k_new.astype(cache.lk.dtype))
-        lv = cache.lv.at[barange, :, cache.ptr].set(v_new.astype(cache.lv.dtype))
-        lg = cache.lg.at[barange, :, cache.ptr].set(g_new.astype(jnp.float32))
-        lpos = cache.lpos.at[barange, cache.ptr].set(cache.t)
+            cache.pkmin, cache.pkmax, dest, vk, can_write, layer=li)
+        # ---- write the new token into the ring (scatter at ptr); an
+        # inactive row aims past the ring and is dropped -------------------
+        at_new = (li, bi, hi, jnp.where(active[:, None], at_ring, w))
+        lk = put_at(cache.lk, at_new, k_new.astype(cache.lk.dtype))
+        lv = put_at(cache.lv, at_new, v_new.astype(cache.lv.dtype))
+        put = at_ptr & active[:, None]                    # [B, W]
+        lg = cache.lg.at[li].set(jnp.where(
+            put[:, None], g_new.astype(jnp.float32)[..., None], lg_l))
+        lpos = cache.lpos.at[li].set(jnp.where(put, t[:, None], lpos_l))
         return cache._replace(
             lk=lk, lv=lv, lg=lg, lpos=lpos,
             gk=gk, gv=gv, gpos=gpos, gcnt=gcnt, overflow=overflow,
             pkmin=pkmin, pkmax=pkmax,
-            t=cache.t + 1, ptr=(cache.ptr + 1) % w,
+            t=cache.t.at[li].set(jnp.where(active, t + 1, t)),
+            ptr=cache.ptr.at[li].set(jnp.where(active, (ptr + 1) % w, ptr)),
         )
+
+
+def _kernel_write(cache: DualCache, k_new, v_new, g_new, *, tau, li, active,
+                  ptr, t, gcnt) -> DualCache:
+    """:func:`lazy_promote_and_write` of stacked leaves as one kernel
+    call; the row counters ``t``/``ptr`` advance here."""
+    w = cache.lk.shape[3]
+    sc = jnp.concatenate([li[None], ptr, t, gcnt.reshape(-1),  # jaxlint: allow-concat(one scalar-prefetch vector of a layer's counters - no batch axis)
+                          active.astype(jnp.int32)])
+    (lk, lv, gk, gv, pkmin, pkmax, lg, lpos, gpos, gcnt_, overflow
+     ) = dual_cache_write(
+        sc, k_new.astype(cache.lk.dtype), v_new.astype(cache.lv.dtype),
+        g_new.astype(jnp.float32), cache.lk, cache.lv, cache.gk, cache.gv,
+        cache.pkmin, cache.pkmax, cache.lg, cache.lpos, cache.gpos,
+        cache.gcnt, cache.overflow, tau=tau, page=PAGE_SIZE,
+        big=float(np.asarray(META_BIG, cache.pkmin.dtype)),
+        interpret=interpret_mode())
+    return cache._replace(
+        lk=lk, lv=lv, lg=lg, lpos=lpos, gk=gk, gv=gv, gpos=gpos, gcnt=gcnt_,
+        overflow=overflow, pkmin=pkmin, pkmax=pkmax,
+        t=cache.t.at[li].set(jnp.where(active, t + 1, t)),
+        ptr=cache.ptr.at[li].set(jnp.where(active, (ptr + 1) % w, ptr)))
 
 
 def cache_kv_for_attention(cache: DualCache) -> Tuple[jax.Array, ...]:

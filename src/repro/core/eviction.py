@@ -12,7 +12,7 @@ less often and prunes *obsolete* rather than *critical* context (Fig. 2b).
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -40,13 +40,22 @@ def init_obs(batch: int, n_q_heads: int, head_dim: int, w_obs: int = 256,
     )
 
 
-def push_query(obs: ObsWindow, q: jax.Array) -> ObsWindow:
-    """q: [B, Hq, hd] — append to ring."""
-    w = obs.w_obs
-    slot = obs.n % w
-    sl = jnp.arange(w)[None] == slot[:, None]  # [B, W]
-    qn = jnp.where(sl[:, None, :, None], q[:, :, None, :].astype(obs.q.dtype), obs.q)
-    return ObsWindow(q=qn, n=obs.n + 1)
+def push_query(obs: ObsWindow, q: jax.Array, *,
+               active: Optional[jax.Array] = None,
+               at: Tuple[jax.Array, ...] = ()) -> ObsWindow:
+    """q: [B, Hq, hd] — append to ring, writing the one slot each row's
+    count points at. ``at`` indexes the window in a stack of windows (its
+    leading axes); ``active`` [B] bool masks rows: an inactive row writes
+    back that slot's query and keeps its count."""
+    w = obs.q.shape[len(at) + 2]
+    n = obs.n[at]
+    b = n.shape[0]
+    if active is None:
+        active = jnp.ones((b,), bool)
+    idx = at + (jnp.arange(b), slice(None), n % w)
+    qn = jnp.where(active[:, None, None], q.astype(obs.q.dtype), obs.q[idx])
+    return ObsWindow(q=obs.q.at[idx].set(qn),
+                     n=obs.n.at[at].set(jnp.where(active, n + 1, n)))
 
 
 def snap_scores(obs: ObsWindow, k: jax.Array, valid: jax.Array,
@@ -107,11 +116,16 @@ def evict_global(cache: DualCache, scores: jax.Array, *,
 
 
 def maybe_evict(cache: DualCache, obs: ObsWindow, *, hard_budget: int,
-                evict_frac: float = 0.10) -> tuple[DualCache, jax.Array]:
-    """Trigger eviction when any head's global count reaches ``hard_budget``.
-    Returns (cache, triggered [B, Hkv] bool)."""
+                evict_frac: float = 0.10,
+                active: Optional[jax.Array] = None
+                ) -> tuple[DualCache, jax.Array]:
+    """Trigger eviction when any head's global count reaches ``hard_budget``
+    (in ``active`` [B] rows only, when given). Returns (cache, triggered
+    [B, Hkv] bool); rows that did not trigger keep their values."""
     gvalid = jnp.arange(cache.budget)[None, None] < cache.gcnt[..., None]
     trig = cache.gcnt >= hard_budget  # [B, H]
+    if active is not None:
+        trig = trig & active[:, None]
     scores = snap_scores(obs, cache.gk, gvalid)
     evicted = evict_global(cache, scores, evict_frac=evict_frac)
     pick = lambda new, old: jnp.where(

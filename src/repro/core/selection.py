@@ -23,7 +23,7 @@ Two consumption modes:
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,33 +87,40 @@ def page_valid_from_count(count: jax.Array, p: int,
 
 
 def update_page_meta_on_write(
-    pkmin: jax.Array,   # [B, H, P, hd]
+    pkmin: jax.Array,   # [B, H, P, hd], or [L, B, H, P, hd] with ``layer``
     pkmax: jax.Array,
     dest: jax.Array,    # [B, H] slot the appended entry lands in
     k_new: jax.Array,   # [B, H, hd] the appended key
     can_write: jax.Array,  # [B, H] bool: append actually happens
     *,
     page_size: int = PAGE_SIZE,
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Incremental metadata maintenance for an append-only cache: fold one
     new key into the single page it touches (true scatter — O(hd) state
     touched per head, never an O(C) rebuild). A write at a page boundary
     starts the page fresh from the sentinel, so stale metadata from
-    pre-eviction occupants can never widen the bound."""
+    pre-eviction occupants can never widen the bound. With ``layer`` the
+    metadata is a stack of layers, written at that index in place; a head
+    that does not append leaves its page as it was."""
     b, h = dest.shape
     pg = dest // page_size
     fresh = (dest % page_size) == 0
-    bi = jnp.arange(b)[:, None].repeat(h, 1)
-    hi = jnp.arange(h)[None, :].repeat(b, 0)
-    old_lo = pkmin[bi, hi, pg]
-    old_hi = pkmax[bi, hi, pg]
+    bi = jnp.broadcast_to(jnp.arange(b)[:, None], (b, h))
+    hi = jnp.broadcast_to(jnp.arange(h)[None, :], (b, h))
+    lead = () if layer is None else (layer,)
+    at = lead + (bi, hi, pg)
+    old_lo = pkmin.at[at].get(mode="promise_in_bounds", unique_indices=True)
+    old_hi = pkmax.at[at].get(mode="promise_in_bounds", unique_indices=True)
     big = jnp.asarray(META_BIG, pkmin.dtype)
     base_lo = jnp.where(fresh[..., None], big, old_lo)
     base_hi = jnp.where(fresh[..., None], -big, old_hi)
     kn = k_new.astype(pkmin.dtype)
-    lo = jnp.where(can_write[..., None], jnp.minimum(base_lo, kn), old_lo)
-    hi_ = jnp.where(can_write[..., None], jnp.maximum(base_hi, kn), old_hi)
-    return pkmin.at[bi, hi, pg].set(lo), pkmax.at[bi, hi, pg].set(hi_)
+    # a head that does not append aims past the pages and is dropped
+    to = lead + (bi, hi, jnp.where(can_write, pg, pkmin.shape[-2]))
+    put = lambda a, v: a.at[to].set(v, mode="drop", unique_indices=True)
+    return (put(pkmin, jnp.minimum(base_lo, kn)),
+            put(pkmax, jnp.maximum(base_hi, kn)))
 
 
 def page_upper_bound(q: jax.Array, meta: PageMeta) -> jax.Array:

@@ -28,6 +28,7 @@ from repro.core.admission import GlobalSelection, select_global
 from repro.core.dual_cache import (
     DualCache,
     cache_kv_for_attention,
+    layer_of,
     lazy_promote_and_write,
 )
 from repro.core.gate import gate_scores, init_gate
@@ -51,14 +52,30 @@ def init_dense_cache(batch: int, n_kv: int, head_dim: int, max_len: int,
     return DenseCache(z, z, jnp.zeros((batch,), jnp.int32))
 
 
-def dense_cache_append(cache: DenseCache, k_new: jax.Array, v_new: jax.Array
-                       ) -> DenseCache:
-    """k_new: [B, H, hd] appended at per-batch position t."""
-    s = cache.k.shape[2]
-    slot = jnp.arange(s)[None] == cache.t[:, None]         # [B, S]
-    k = jnp.where(slot[:, None, :, None], k_new[:, :, None, :].astype(cache.k.dtype), cache.k)
-    v = jnp.where(slot[:, None, :, None], v_new[:, :, None, :].astype(cache.v.dtype), cache.v)
-    return DenseCache(k, v, cache.t + 1)
+def dense_cache_append(cache: DenseCache, k_new: jax.Array, v_new: jax.Array,
+                       *, layer: Optional[jax.Array] = None,
+                       active: Optional[jax.Array] = None) -> DenseCache:
+    """k_new: [B, H, hd] appended at per-batch position t (one scattered
+    slot; a write at or past capacity is dropped). With ``layer`` the cache
+    is a stack of layers written at that index in place; ``active`` [B]
+    bool masks rows at the write (an inactive row writes back its slot
+    and keeps its length)."""
+    if layer is None:
+        out = dense_cache_append(jax.tree.map(lambda a: a[None], cache),
+                                 k_new, v_new, layer=jnp.zeros((), jnp.int32),
+                                 active=active)
+        return jax.tree.map(lambda a: a[0], out)
+    b = k_new.shape[0]
+    if active is None:
+        active = jnp.ones((b,), bool)
+    t = cache.t[layer]
+    idx = (layer, jnp.arange(b), slice(None), t)
+    a3 = active[:, None, None]
+    k = cache.k.at[idx].set(
+        jnp.where(a3, k_new.astype(cache.k.dtype), cache.k[idx]), mode="drop")
+    v = cache.v.at[idx].set(
+        jnp.where(a3, v_new.astype(cache.v.dtype), cache.v[idx]), mode="drop")
+    return DenseCache(k, v, cache.t.at[layer].set(jnp.where(active, t + 1, t)))
 
 
 # ==========================================================================
@@ -344,6 +361,9 @@ def _rope_single(cfg: ModelConfig, x: jax.Array, t: jax.Array) -> jax.Array:
 
 def attn_decode_wgkv(p: Params, cfg: ModelConfig, x_t: jax.Array,
                      cache: DualCache, *,
+                     layer: Optional[jax.Array] = None,
+                     active: Optional[jax.Array] = None,
+                     write_kernel: bool = False,
                      gate_override: Optional[jax.Array] = None,
                      token_select_fn: Optional[Callable] = None,
                      select_pages_k: Optional[int] = None
@@ -372,20 +392,24 @@ def attn_decode_wgkv(p: Params, cfg: ModelConfig, x_t: jax.Array,
     bit-identical to the full path. Mutually exclusive with
     ``token_select_fn``.
 
+    ``layer``: ``cache`` is a stack of layers (``[L, B, ...]`` leaves) and
+    this step reads and writes layer ``layer`` of it in place; ``active``
+    [B] bool masks rows at the write, and ``write_kernel`` makes it the
+    Pallas kernel (``lazy_promote_and_write``'s ``kernel``).
+
     Returns (out [B, D], new cache, g_new [B, Hkv], sel_pages) where
     sel_pages is [B, Hkv] valid selected-page counts (None when the
-    gathered path is off)."""
+    gathered path is off); the new cache is stacked when ``cache`` is."""
     b, d_model = x_t.shape
+    t = cache.t if layer is None else cache.t[layer]
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with jax.named_scope("proj"):
-        x = x_t[:, None, :]  # [B,1,D]
-        q = _heads(x @ p["w_q"].astype(x.dtype), hq, hd)[:, :, 0]   # [B,Hq,hd]
-        k_pre = _heads(x @ p["w_k"].astype(x.dtype), hkv, hd)[:, :, 0]
-        v_new = _heads(x @ p["w_v"].astype(x.dtype), hkv, hd)[:, :, 0]
-        q, k_pre = _qk_norm(p, q[:, :, None], k_pre[:, :, None])
-        q, k_pre = q[:, :, 0], k_pre[:, :, 0]
-        q = _rope_single(cfg, q, cache.t)
-        k_new = _rope_single(cfg, k_pre, cache.t)
+        q = (x_t @ p["w_q"].astype(x_t.dtype)).reshape(b, hq, hd)
+        k_pre = (x_t @ p["w_k"].astype(x_t.dtype)).reshape(b, hkv, hd)
+        v_new = (x_t @ p["w_v"].astype(x_t.dtype)).reshape(b, hkv, hd)
+        q, k_pre = _qk_norm(p, q, k_pre)
+        q = _rope_single(cfg, q, t)
+        k_new = _rope_single(cfg, k_pre, t)
     if gate_override is not None:
         g_new = gate_override
     else:
@@ -394,7 +418,10 @@ def attn_decode_wgkv(p: Params, cfg: ModelConfig, x_t: jax.Array,
                                 k_new[:, :, None])[..., 0]
 
     # update first (promote victim, write self), then attend — see docstring
-    new_cache = lazy_promote_and_write(cache, k_new, v_new, g_new, tau=cfg.wgkv.tau)
+    new_stack = lazy_promote_and_write(cache, k_new, v_new, g_new,
+                                       tau=cfg.wgkv.tau, layer=layer,
+                                       active=active, kernel=write_kernel)
+    new_cache = new_stack if layer is None else layer_of(new_stack, layer)
     sel_pages = None
     if select_pages_k is not None:
         assert token_select_fn is None, "mask and gather selection are exclusive"
@@ -429,14 +456,18 @@ def attn_decode_wgkv(p: Params, cfg: ModelConfig, x_t: jax.Array,
         o = jnp.einsum("bhgk,bhkd->bhgd", wts.astype(v_all.dtype), v_all)
     with jax.named_scope("proj"):
         y = o.reshape(b, hq * hd) @ p["w_o"].astype(x_t.dtype)
-    return y, new_cache, g_new, sel_pages
+    return y, new_stack, g_new, sel_pages
 
 
 def attn_decode_dense(p: Params, cfg: ModelConfig, x_t: jax.Array,
-                      cache: DenseCache, *, window: Optional[int] = None
+                      cache: DenseCache, *, window: Optional[int] = None,
+                      layer: Optional[jax.Array] = None,
+                      active: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, DenseCache]:
-    """Full-attention baseline decode step. x_t: [B, D]."""
+    """Full-attention baseline decode step. x_t: [B, D]. ``layer`` and
+    ``active`` as in :func:`attn_decode_wgkv`."""
     b, _ = x_t.shape
+    t = cache.t if layer is None else cache.t[layer]
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with jax.named_scope("proj"):
         x = x_t[:, None, :]
@@ -445,10 +476,12 @@ def attn_decode_dense(p: Params, cfg: ModelConfig, x_t: jax.Array,
         v_new = _heads(x @ p["w_v"].astype(x.dtype), hkv, hd)[:, :, 0]
         q, k_pre = _qk_norm(p, q[:, :, None], k_pre[:, :, None])
         q, k_pre = q[:, :, 0], k_pre[:, :, 0]
-        q = _rope_single(cfg, q, cache.t)
-        k_new = _rope_single(cfg, k_pre, cache.t)
+        q = _rope_single(cfg, q, t)
+        k_new = _rope_single(cfg, k_pre, t)
     with jax.named_scope("cache_write"):
-        cache = dense_cache_append(cache, k_new, v_new)
+        new_stack = dense_cache_append(cache, k_new, v_new, layer=layer,
+                                       active=active)
+    cache = new_stack if layer is None else layer_of(new_stack, layer)
     with jax.named_scope("attention"):
         s = cache.k.shape[2]
         pos = jnp.arange(s)[None]                                   # [1, S]
@@ -464,7 +497,7 @@ def attn_decode_dense(p: Params, cfg: ModelConfig, x_t: jax.Array,
         o = jnp.einsum("bhgk,bhkd->bhgd", wts.astype(cache.v.dtype), cache.v)
     with jax.named_scope("proj"):
         y = o.reshape(b, hq * hd) @ p["w_o"].astype(x_t.dtype)
-    return y, cache
+    return y, new_stack
 
 
 # ==========================================================================

@@ -29,7 +29,8 @@ from repro.configs.base import ModelConfig
 from repro.core import baselines as BL
 from repro.core import eviction as EV
 from repro.core import selection as SEL
-from repro.core.dual_cache import DualCache, init_dual_cache, prefill_populate
+from repro.core.dual_cache import (DualCache, init_dual_cache, layer_of,
+                                   prefill_populate)
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as MoE
@@ -58,6 +59,10 @@ class DecodeOptions:
     admission_policy: Optional[str] = None
     admission_sink: int = 16
     duo_retrieval_heads: Tuple[int, ...] = ()
+    # the dual cache's decode write as one Pallas kernel a layer
+    # (kernels/cache_write.py) instead of the jnp scatters, bit-identical:
+    # for a program on one TPU device, which the serving engine sets
+    write_kernel: bool = False
 
 
 def parse_selection_policy(policy: Optional[str]) -> Optional[int]:
@@ -251,8 +256,15 @@ def _quest_mask(cfg: ModelConfig, cache: DualCache, q: jax.Array,
     return jnp.concatenate([gmask, rest], axis=-1)  # jaxlint: allow-concat(joins along the kv-position axis - batch axis untouched)
 
 
+_ATTN_BLOCKS = ("attn", "attn_moe", "local_attn", "attn_cross")
+
+
 def _attn_block_decode(p, cfg: ModelConfig, bt: str, x_t, cache, *,
-                       opts: DecodeOptions, obs=None, moe_groups: int):
+                       opts: DecodeOptions, obs=None, obs_at=(),
+                       layer=None, active=None, moe_groups: int):
+    """One attention block at one position. With ``layer`` set, ``cache``
+    (and ``obs``, indexed by ``obs_at``) are stacks written in place at
+    that layer; ``active`` [B] masks rows at every write."""
     with jax.named_scope("proj"):
         xin = _norm(cfg, p["ln1"], x_t[:, None])[:, 0]
     self_cache = cache["self"] if bt == "attn_cross" else cache
@@ -264,30 +276,40 @@ def _attn_block_decode(p, cfg: ModelConfig, bt: str, x_t, cache, *,
         sel_fn = None
         if opts.quest_pages is not None:
             sel_fn = lambda cache, q: _quest_mask(cfg, cache, q, opts.quest_pages)
+        t = self_cache.t if layer is None else self_cache.t[layer]
         h, new_cache, g_new, sel_pages = A.attn_decode_wgkv(
-            p["attn"], cfg, xin, self_cache, token_select_fn=sel_fn,
+            p["attn"], cfg, xin, self_cache, layer=layer, active=active,
+            write_kernel=opts.write_kernel, token_select_fn=sel_fn,
             select_pages_k=parse_selection_policy(opts.selection_policy),
-            gate_override=_static_gates(cfg, opts, self_cache.t))
+            gate_override=_static_gates(cfg, opts, t))
         adm = (g_new >= cfg.wgkv.tau).mean(axis=-1)  # per-row [B]
         if sel_pages is not None:
             selp = sel_pages.astype(jnp.float32).mean(axis=-1)  # per-row [B]
         if opts.evict_hard_budget is not None and obs is not None:
             q_obs = A._heads((xin[:, None] @ p["attn"]["w_q"].astype(xin.dtype)),
                              cfg.n_heads, cfg.head_dim)[:, :, 0]
-            obs = EV.push_query(obs, q_obs)
-            new_cache, trg = EV.maybe_evict(
-                new_cache, obs, hard_budget=opts.evict_hard_budget,
-                evict_frac=opts.evict_frac)
+            obs = EV.push_query(obs, q_obs, active=active, at=obs_at)
+            view = new_cache if layer is None else layer_of(new_cache, layer)
+            evicted, trg = EV.maybe_evict(
+                view, layer_of(obs, obs_at), hard_budget=opts.evict_hard_budget,
+                evict_frac=opts.evict_frac, active=active)
+            # a compaction rewrites the layer, but only triggered rows
+            # take new values (maybe_evict keeps the others)
+            new_cache = evicted if layer is None else jax.tree.map(
+                lambda st, v: st.at[layer].set(v), new_cache, evicted)
             trig = trg.astype(jnp.float32).mean(axis=-1)  # per-row [B]
     else:
         h, new_cache = A.attn_decode_dense(p["attn"], cfg, xin, self_cache,
-                                           window=window)
+                                           window=window, layer=layer,
+                                           active=active)
     x_t = x_t + h
     if bt == "attn_cross":
+        cross = cache["cross"]
         x_t = x_t + A.attn_cross(p["xattn"], cfg,
                                  _norm(cfg, p["ln_x"], x_t[:, None]),
-                                 cache["cross"])[:, 0]
-        new_cache = {"self": new_cache, "cross": cache["cross"]}
+                                 cross if layer is None
+                                 else layer_of(cross, layer))[:, 0]
+        new_cache = {"self": new_cache, "cross": cross}
     with jax.named_scope("mlp"):
         if bt == "attn_moe":
             y, _ = MoE.moe_ffn(p["moe"], cfg,
@@ -304,32 +326,49 @@ def _attn_block_decode(p, cfg: ModelConfig, bt: str, x_t, cache, *,
 
 
 def _block_decode(p, cfg: ModelConfig, bt: str, x_t, cache, *, opts, obs,
-                  moe_groups):
-    if bt in ("attn", "attn_moe", "local_attn", "attn_cross"):
+                  moe_groups, obs_at=(), layer=None, active=None):
+    if bt in _ATTN_BLOCKS:
         return _attn_block_decode(p, cfg, bt, x_t, cache, opts=opts, obs=obs,
+                                  obs_at=obs_at, layer=layer, active=active,
                                   moe_groups=moe_groups)
     zero = jnp.zeros((), jnp.float32)
+    # recurrent states are small ([B, ...] a layer): step the layer's
+    # state, keep inactive rows' old values, write the layer back
+    old = cache if layer is None else layer_of(cache, layer)
     if bt == "rglru":
         y, state = RG.rglru_step(p["rec"], cfg,
-                                 _norm(cfg, p["ln1"], x_t[:, None])[:, 0], cache)
+                                 _norm(cfg, p["ln1"], x_t[:, None])[:, 0], old)
         x_t = x_t + y
         x_t = x_t + L.swiglu(p["mlp"], _norm(cfg, p["ln2"], x_t[:, None]))[:, 0]
-        return x_t, state, obs, zero, None, None
-    if bt == "mlstm":
-        x_t, state = XL.mlstm_step(p["cell"], cfg, x_t, cache)
-        return x_t, state, obs, zero, None, None
-    if bt == "slstm":
-        x_t, state = XL.slstm_step(p["cell"], cfg, x_t, cache)
-        return x_t, state, obs, zero, None, None
-    raise ValueError(bt)
+    elif bt == "mlstm":
+        x_t, state = XL.mlstm_step(p["cell"], cfg, x_t, old)
+    elif bt == "slstm":
+        x_t, state = XL.slstm_step(p["cell"], cfg, x_t, old)
+    else:
+        raise ValueError(bt)
+    if active is not None:
+        state = jax.tree.map(
+            lambda n, o: jnp.where(
+                active.reshape((-1,) + (1,) * (n.ndim - 1)), n, o),
+            state, old)
+    if layer is not None:
+        state = jax.tree.map(lambda st, v: st.at[layer].set(v), cache, state)
+    return x_t, state, obs, zero, None, None
 
 
 def decode_step(params: Params, cfg: ModelConfig, token: jax.Array,
                 caches: CacheTree, *, moe_groups: int = 1,
                 opts: DecodeOptions = DecodeOptions(),
-                scan_unroll: bool = False
+                scan_unroll: bool = False,
+                active: Optional[jax.Array] = None,
                 ) -> Tuple[jax.Array, CacheTree, Dict[str, jax.Array]]:
-    """token: [B] int32 -> (logits [B, V], new caches, stats)."""
+    """token: [B] int32 -> (logits [B, V], new caches, stats).
+
+    ``active`` [B] bool masks rows at every cache write: an inactive row
+    writes back what the slots it touches held and keeps its counters, so
+    its state comes out bit-identical (None: every row advances). The
+    layer scan carries the stacked ``[n_repeats, B, ...]`` caches and
+    writes each layer's touched slots into them in place."""
     dt = jnp.dtype(cfg.dtype)
     with jax.named_scope("embed"):
         x = L.embed(params["embed"], token[:, None], dt)[:, 0]  # [B, D]
@@ -342,13 +381,14 @@ def decode_step(params: Params, cfg: ModelConfig, token: jax.Array,
         ang = t[:, None].astype(jnp.float32) * inv[None]
         x = x + jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], -1).astype(dt)  # jaxlint: allow-concat(feature-axis sinusoid halves - batch axis untouched)
 
-    new_caches: CacheTree = {"t": t + 1}
+    new_caches: CacheTree = {
+        "t": t + 1 if active is None else jnp.where(active, t + 1, t)}
     trig_sum = jnp.zeros((b,), jnp.float32)  # per-row eviction triggers
     adm_sum = jnp.zeros((b,), jnp.float32)  # per-row: batch rows may be dead
     adm_n = jnp.zeros((), jnp.float32)
     sel_sum = jnp.zeros((b,), jnp.float32)  # per-row selected pages (layer sum)
     bd = functools.partial(_block_decode, cfg=cfg, opts=opts,
-                           moe_groups=moe_groups)
+                           moe_groups=moe_groups, active=active)
     stem_new = []
     for i, bt in enumerate(cfg.stem_pattern):
         x, c, _, trg, adm, selp = bd(params["stem"][i], bt=bt, x_t=x,
@@ -362,50 +402,38 @@ def decode_step(params: Params, cfg: ModelConfig, token: jax.Array,
     if stem_new:
         new_caches["stem"] = tuple(stem_new)
 
-    has_obs = "obs" in caches
-
     x = constrain_tokens(x)
 
     def body(carry, xs):
-        xc, trig, asum, an, ssum = carry
+        xc, trig, asum, an, ssum, blocks, obs = carry
+        bp, r = xs
         xc = constrain_tokens(xc)
-        if has_obs:
-            bp, bc, obs_b = xs
-        else:
-            bp, bc = xs
-            obs_b = None
-        new_bc = {}
-        new_obs = []
+        blocks = dict(blocks)
         ai = 0
         for i, bt in enumerate(cfg.block_pattern):
-            obs_i = None
-            if obs_b is not None and bt in ("attn", "attn_moe", "local_attn", "attn_cross"):
-                obs_i = jax.tree.map(lambda v: v[ai], obs_b)
-            xc, c, obs_o, trg, adm, selp = bd(bp[f"b{i}"], bt=bt, x_t=xc,
-                                              cache=bc[f"b{i}"], obs=obs_i)
-            new_bc[f"b{i}"] = c
-            if obs_i is not None:
-                new_obs.append(obs_o)
+            at_obs = obs is not None and bt in _ATTN_BLOCKS
+            xc, blocks[f"b{i}"], obs_o, trg, adm, selp = bd(
+                bp[f"b{i}"], bt=bt, x_t=xc, cache=blocks[f"b{i}"],
+                obs=obs if at_obs else None, obs_at=(r, ai), layer=r)
+            if at_obs:
+                obs = obs_o
                 ai += 1
             trig = trig + trg
             if adm is not None:
                 asum, an = asum + adm, an + 1.0
             if selp is not None:
                 ssum = ssum + selp
-        # jaxlint: allow-concat(stacks per-repeat obs on a NEW leading axis - rows replicate)
-        ys = (new_bc, jax.tree.map(lambda *v: jnp.stack(v), *new_obs)) if new_obs \
-            else (new_bc,)
-        return (xc, trig, asum, an, ssum), ys
+        return (xc, trig, asum, an, ssum, blocks, obs), None
 
-    xs = (params["blocks"], caches["blocks"], caches["obs"]) if has_obs \
-        else (params["blocks"], caches["blocks"])
     with jax.named_scope("layer_scan"):
-        (x, trig_sum, adm_sum, adm_n, sel_sum), ys = jax.lax.scan(
-            body, (x, trig_sum, adm_sum, adm_n, sel_sum), xs,
+        (x, trig_sum, adm_sum, adm_n, sel_sum, blocks, obs), _ = jax.lax.scan(
+            body, (x, trig_sum, adm_sum, adm_n, sel_sum, caches["blocks"],
+                   caches.get("obs")),
+            (params["blocks"], jnp.arange(cfg.n_repeats, dtype=jnp.int32)),
             unroll=scan_unroll)
-    new_caches["blocks"] = ys[0]
-    if has_obs:
-        new_caches["obs"] = ys[1]
+    new_caches["blocks"] = blocks
+    if obs is not None:
+        new_caches["obs"] = obs
     with jax.named_scope("lm_head"):
         hidden = _norm(cfg, params["ln_f"], x[:, None])[:, 0]
         logits = L.unembed(params["embed"], hidden)
@@ -457,11 +485,13 @@ def prefill_extend_ragged(params: Params, cfg: ModelConfig,
     ``tokens`` [B, S] holds each row's next prompt chunk left-aligned;
     ``lengths`` [B] says how many of those S positions are real. Every
     position runs through :func:`decode_step` exactly like the batch-1
-    extend, but all cache writes (KV, ring pointer, gate/eviction state)
-    at positions >= ``lengths[i]`` are masked out by a per-row select
-    against the pre-step tree — a short row's final cache state is
+    extend, with the row mask of that position: every cache write (KV,
+    ring pointer, gate/eviction state) at positions >= ``lengths[i]``
+    writes back what the slot held, so a short row's final cache state is
     bit-identical to running the sequential scan over its real tokens
-    only, and a length-0 row is pure padding. Returns
+    only, and a length-0 row is pure padding. Only the slots a position
+    touches are written, in place; nothing selects over the whole tree.
+    Returns
 
       * ``last_logits`` [B, V]: each row's logits at its LAST real
         position (zeros for length-0 rows — the caller keeps its prior
@@ -480,11 +510,6 @@ def prefill_extend_ragged(params: Params, cfg: ModelConfig,
     so opens, mid-prefill extends, and decode steps share this one
     compiled call.
     """
-    # batch axes differ per subtree ("t"/stem batch-leading, "blocks"
-    # stacked [n_repeats, B, ...], "obs" [n_repeats, n_attn, B, ...]);
-    # the splice helpers own that rule (lazy import: no load-time cycle)
-    from repro.launch.specs import cache_batch_axis
-
     b, s = tokens.shape
     active_mat = (jnp.arange(s, dtype=jnp.int32)[:, None]
                   < lengths[None, :].astype(jnp.int32))       # [S, B]
@@ -496,22 +521,15 @@ def prefill_extend_ragged(params: Params, cfg: ModelConfig,
     def body(carry, xs):  # jaxlint: masked-scan-body
         old, last_logits = carry
         tok, active = xs                                      # [B], [B] bool
-
-        def keep(path, new_leaf, old_leaf):
-            shape = [1] * jnp.ndim(new_leaf)
-            shape[cache_batch_axis(path)] = b
-            return jnp.where(active.reshape(shape), new_leaf, old_leaf)
-
         logits, new, st = decode_step(params, cfg, tok, old,
                                       moe_groups=moe_groups, opts=opts,
-                                      scan_unroll=scan_unroll)
+                                      scan_unroll=scan_unroll, active=active)
         with jax.named_scope("row_keep"):
-            merged = jax.tree_util.tree_map_with_path(keep, new, old)
             last_logits = jnp.where(active[:, None], logits, last_logits)
-        trig = jnp.where(active, st["evict_trigger_rows"], 0.0)
-        adm = jnp.where(active, st["mean_admission"], 0.0)
-        selp = jnp.where(active, st["selected_pages_rows"], 0.0)
-        return (merged, last_logits), (trig, adm, selp)
+            trig = jnp.where(active, st["evict_trigger_rows"], 0.0)
+            adm = jnp.where(active, st["mean_admission"], 0.0)
+            selp = jnp.where(active, st["selected_pages_rows"], 0.0)
+        return (new, last_logits), (trig, adm, selp)
 
     init = (caches, jnp.zeros(logits_s.shape, logits_s.dtype))
     with jax.named_scope("position_scan"):

@@ -74,8 +74,8 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     inv = rope_freqs(hd, theta)  # [hd/2]
     ang = positions[..., None].astype(jnp.float32) * inv  # [..., T, hd/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., 0::2], x32[..., 1::2]
+    x32 = x.astype(jnp.float32).reshape(x.shape[:-1] + (hd // 2, 2))
+    x1, x2 = x32[..., 0], x32[..., 1]
     r1 = x1 * cos - x2 * sin
     r2 = x2 * cos + x1 * sin
     out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
@@ -112,8 +112,8 @@ def apply_mrope(x: jax.Array, positions3: jax.Array, theta: float) -> jax.Array:
     )
     ang = slot_pos * inv  # [..., T, hd/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., 0::2], x32[..., 1::2]
+    x32 = x.astype(jnp.float32).reshape(x.shape[:-1] + (hd // 2, 2))
+    x1, x2 = x32[..., 0], x32[..., 1]
     out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).reshape(x.shape)
     return out.astype(x.dtype)
 

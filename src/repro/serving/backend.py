@@ -20,8 +20,8 @@ Protocol surface (one request = one chunked prefill + one decode slot):
     separately-compiled batch-1 open), a mid-prefill row takes its next
     prompt chunk, a live decode row piggybacks as a length-1 ragged row
     fed from the on-device sampled-token vector, and a dead row is
-    length-0 padding whose state is kept bit-identical by per-leaf
-    masked writes. Sampling runs inside the same jitted call; the
+    length-0 padding whose state is kept bit-identical by row-masked
+    writes. Sampling runs inside the same jitted call; the
     result is an uncollected :class:`FusedStep`. A task-less
     ``step_batch([])`` is the decode-only dispatch — and, when the
     backend was built with a ``selection`` policy (``"quest:K"``), the
@@ -194,6 +194,12 @@ class FusedStep(InflightStep):
     # [slots, V] device logits each row sampled ``tokens`` from (its
     # last real position this step); a reference, never pulled by collect
     logits: Any = None
+    # the call was given its input tree to update in place: that tree is
+    # deleted, and ``after`` may be too once the next step is dispatched
+    donated: bool = False
+    # slot -> batch-1 snapshot of that row of ``after``, taken at dispatch
+    # (``hold_prefix_row``) for a prefix capture at collect
+    rows: Dict[int, Any] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -259,6 +265,11 @@ class EngineBackend(Protocol):
     # of the empty template on the task's first dispatch.
     def capture_prefix(self, step: FusedStep, slot: int, key: str, *,
                        adm_weighted: float = 0.0) -> Any: ...
+
+    # snapshot row ``slot`` of a just-dispatched step's output tree for
+    # capture_prefix (an enqueued device slice, no sync): a backend that
+    # donates its cache tree to the next dispatch deletes ``step.after``
+    def hold_prefix_row(self, step: FusedStep, slot: int) -> None: ...
 
     def release_prefix(self, entry: Any) -> None: ...
 

@@ -166,9 +166,9 @@ class DenseEngine(Engine):
 
     def capture_prefix(self, step, slot: int, key: str, *,
                        adm_weighted: float = 0.0):
-        from repro.launch.specs import cache_tree_bytes, extract_slot_caches
+        from repro.launch.specs import cache_tree_bytes
         from repro.serving.prefix_cache import CachedPrefix
-        caches = extract_slot_caches(step.after, slot)
+        caches = step.rows.pop(slot)
         n = int(jax.device_get(caches["t"])[0])
         layers = self._iter_dense(caches)
         heads = layers[0].k.shape[1] if layers else 0

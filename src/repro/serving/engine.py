@@ -77,7 +77,7 @@ from repro.serving.backend import (BackendCapabilities, FusedStep,  # noqa: F401
                                    InflightStep, Prefix, PrefillTask)
 from repro.serving.obs.trace import NULL_TRACER
 from repro.serving.sampling import sample
-from repro.serving.sharded import ShardedDecodeMixin
+from repro.serving.sharded import InPlaceStep, ShardedDecodeMixin
 
 
 @dataclasses.dataclass
@@ -141,13 +141,21 @@ class Engine(ShardedDecodeMixin):
             self.pool = paged.PagedKVPool(slots * self._row_pages + 1,
                                           cfg.head_dim)
         self.params = self._sharding_setup(params, mesh)
+        if self.mesh is None and jax.default_backend() == "tpu":
+            # one device: the decode write runs as the Pallas kernel; a
+            # mesh keeps the jnp scatters its partitioner splits
+            self.opts = dataclasses.replace(self.opts, write_kernel=True)
         self._extend_batch = self._make_extend_batch()
-        self._fused = self._make_fused_step()
+        # the fused step updates the cache tree in place (donation) unless
+        # something reads its input tree after the call: the paged
+        # mirror's collect diffs ``FusedStep.before`` against ``after``
+        donate = not mirror_paged
+        self._fused = self._make_fused_step(donate=donate)
         self._fused_sel = None if self.selection is None \
             else self._make_fused_step(
                 dataclasses.replace(self.opts,
                                     selection_policy=self.selection),
-                kind="fused_step_sel")
+                kind="fused_step_sel", donate=donate)
         self._tok_dev = jnp.zeros((slots,), jnp.int32)
         # fused path: which rows of the persistent batched tree hold a
         # mid-prefill task's state (spliced empty at its first step_batch)
@@ -177,6 +185,9 @@ class Engine(ShardedDecodeMixin):
                       # fused prefill-stage tokens/s
                       "fused_steps": 0.0, "fused_time_s": 0.0,
                       "fused_prefill_time_s": 0.0,
+                      # fused steps whose call updated the cache tree in
+                      # place (donated it); all of them without the mirror
+                      "fused_donated_steps": 0.0,
                       "fused_prefill_tokens": 0.0,
                       # fixed-shape padding accounting: every fused
                       # dispatch pays for ``slots`` rows whatever their
@@ -229,7 +240,8 @@ class Engine(ShardedDecodeMixin):
             if fn is None:
                 continue
             size = getattr(fn, "_cache_size", None)
-            out[kind] = (int(size()) if size is not None else
+            n = size() if size is not None else None
+            out[kind] = (int(n) if n is not None else
                          sum(1 for k in self._fn_cache if k and k[0] == kind))
         return out
 
@@ -469,7 +481,7 @@ class Engine(ShardedDecodeMixin):
         prompt; with ``decode`` every live slot not taking a chunk joins
         as a length-1 row fed from the ON-DEVICE sampled-token vector;
         all other rows are length-0 padding kept bit-identical by the
-        scan's per-leaf masked writes. Sampling runs inside the same
+        scan's row-masked writes. Sampling runs inside the same
         jitted call, so a finishing row's first generated token and every
         decode row's next token come back together from :meth:`collect`.
 
@@ -562,17 +574,22 @@ class Engine(ShardedDecodeMixin):
             self.key, sk = jax.random.split(self.key)
             before = self.caches
             mirror = self.mirror
+            fused = self._fused_sel if use_sel else self._fused
+            # donate only through the engine's own step: a step replaced
+            # by a wrapper may read the tree it was given after the call
+            donated = isinstance(fused, InPlaceStep) and fused.donate
+            if donated:
+                fused = fused.in_place
             feed = (jnp.asarray(toks), jnp.asarray(lengths), self._tok_dev,
                     jnp.asarray(use_dev), sk[None])
         with self.tracer.span("fused_call"):
             if use_sel:
                 with self.tracer.span("selection", k=self._sel_k,
                                       rows=len(decode_rows)):
-                    logits, self.caches, st = self._fused_sel(
+                    logits, self.caches, st = fused(
                         self.params, feed, before)
             else:
-                logits, self.caches, st = self._fused(
-                    self.params, feed, before)
+                logits, self.caches, st = fused(self.params, feed, before)
         with self.tracer.span("fused_bookkeep"):
             sampled = st["sampled"]
             # host bookkeeping at dispatch (teacher-forced, deterministic):
@@ -601,11 +618,11 @@ class Engine(ShardedDecodeMixin):
             return FusedStep(
                 tokens=sampled, stats=st,
                 before=before if mirror else None,
-                # ``after`` is kept unconditionally (a tree of references,
-                # no device copy): prefix capture snapshots it at collect
-                # even when the host paged mirror is off (timed/meshed
-                # engines)
-                after=self.caches,
+                # ``after`` is the live tree (references, no device copy);
+                # when the fused call donates, the next dispatch deletes
+                # it, so a prefix capture holds its row at dispatch
+                # (``hold_prefix_row``) and collect never reads it
+                after=self.caches, donated=donated,
                 live=tuple(self.live), gen=tuple(self._slot_gen),
                 tasks=tuple(tasks), takes=tuple(takes), fulls=tuple(fulls),
                 finishing=tuple(finishing), decode_rows=decode_rows,
@@ -653,6 +670,7 @@ class Engine(ShardedDecodeMixin):
         # a true device+host measure of the whole dispatched step
         wall = time.perf_counter() - step.t_dispatch
         self.stats["fused_steps"] += 1
+        self.stats["fused_donated_steps"] += float(step.donated)
         self.stats["fused_time_s"] += wall
         if step.had_prefill:
             self.stats["fused_prefill_time_s"] += wall
@@ -770,21 +788,28 @@ class Engine(ShardedDecodeMixin):
                 self.pool.free_stream(dst)
                 self.pool.share_stream(skey, dst)
 
+    def hold_prefix_row(self, step: FusedStep, slot: int) -> None:
+        """Snapshot row ``slot`` of a just-dispatched step's output tree
+        as a batch-1 tree for :meth:`capture_prefix` at collect. An
+        enqueued device slice, no sync; it is taken before the next
+        dispatch can donate (and so delete) ``step.after``."""
+        step.rows[slot] = extract_slot_caches(step.after, slot)
+
     def capture_prefix(self, step: FusedStep, slot: int, key: str, *,
                        adm_weighted: float = 0.0):
         """Freeze row ``slot`` of a collected step into a shareable
         :class:`~repro.serving.prefix_cache.CachedPrefix`: the batch-1
-        device tree (immutable — later dispatches update the batched tree
-        functionally and cannot disturb it), the per-layer host counters
-        the suffix mirror needs, and — when mirroring — entry-owned pool
-        streams holding the post-admission bytes, ready to be aliased
-        into a hitting slot with zero copies.
+        device tree (its own buffers, held at dispatch — later dispatches
+        update the batched tree in place and cannot disturb it), the
+        per-layer host counters the suffix mirror needs, and — when
+        mirroring — entry-owned pool streams holding the post-admission
+        bytes, ready to be aliased into a hitting slot with zero copies.
 
         A sanctioned sync point (:data:`SyncSentinel.SANCTIONED`): the
         per-layer pulls run once per unique prefix, off the dispatch
         window, exactly like insert's mirror."""
         from repro.serving.prefix_cache import CachedPrefix
-        caches = extract_slot_caches(step.after, slot)
+        caches = step.rows.pop(slot)
         if self.mirror:
             # the entry's streams hold at most one row's pages
             self._pool_entries.add(key)

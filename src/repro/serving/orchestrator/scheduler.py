@@ -70,7 +70,7 @@ _ENGINE_STAT_KEYS = ("evict_triggers", "decode_adm_sum",
                      # fused megabatch ticks: dispatch->collect wall and
                      # the prefill-stage share (the compile-free
                      # prefill tokens/s numerator bench_serving reports)
-                     "fused_steps", "fused_time_s",
+                     "fused_steps", "fused_time_s", "fused_donated_steps",
                      "fused_prefill_time_s", "fused_prefill_tokens",
                      # fixed-shape padding accounting (active vs padded
                      # rows per fused dispatch -> fused_padding_frac)
@@ -413,14 +413,18 @@ class Orchestrator:
             if task.capture_plan is not None:
                 n, key = task.capture_plan
                 if task.pos == n:
+                    # the row is snapshot now: the next dispatch may
+                    # donate (delete) this step's output tree
+                    self.engine.hold_prefix_row(step, task.slot)
                     self._captures.setdefault(id(step), []).append(
                         (req, task, n, key))
                 if task.pos >= n:
                     task.capture_plan = None
 
     def _run_captures(self, step) -> None:
-        """Mature this collected step's capture obligations: snapshot the
-        slot's post-admission cache state (``capture_prefix`` is a
+        """Mature this collected step's capture obligations: freeze the
+        slot's post-admission cache state held at dispatch
+        (``capture_prefix`` is a
         sanctioned host sync, like the collect that just ran) and insert
         it into the store. FIFO collect means the task's ``adm_weighted``
         covers exactly the captured prefix here."""

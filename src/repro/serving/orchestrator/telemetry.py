@@ -106,6 +106,9 @@ class Telemetry:
                 # fused_prefill_time_s/_tokens apportion the fused call's
                 # wall time to its prefill rows for the prompt-ingest rate
                 ("fused_steps", 0), ("fused_time_s", 0.0),
+                # fused steps that updated the cache tree in place
+                # (donated it): all of them unless the paged mirror is on
+                ("fused_donated_steps", 0),
                 ("fused_prefill_time_s", 0.0), ("fused_prefill_tokens", 0),
                 # fixed-shape padding accounting of fused dispatches
                 # (fused_padding_frac = 1 - active/slot rows)

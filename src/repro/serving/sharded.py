@@ -89,6 +89,34 @@ def build_mesh(spec: Optional[str]) -> Optional[Mesh]:
     return make_auto_mesh(shape, ("data", "model"))
 
 
+class InPlaceStep:
+    """A jitted step in two forms that differ only in whether they donate
+    the cache tree (argument 2), each compiled on first use. Calling the
+    step never donates, so a caller may read the tree it passed after the
+    call; :meth:`in_place` runs the form ``donate`` chose at build, which
+    (when True) updates the tree in place and deletes the caller's input
+    tree. :meth:`lower` compiles that same form."""
+
+    def __init__(self, forms: Dict[bool, Callable], donate: bool):
+        self._forms = forms
+        self.donate = donate
+
+    def __call__(self, params, feed, caches):
+        return self._forms[False](params, feed, caches)
+
+    def in_place(self, params, feed, caches):
+        return self._forms[self.donate](params, feed, caches)
+
+    def lower(self, *args):
+        return self._forms[self.donate].lower(*args)
+
+    def _cache_size(self) -> Optional[int]:
+        """Compiled entries of both forms; None under a mesh, whose forms
+        memoize their compiled entries in the backend's ``_fn_cache``."""
+        sizes = [getattr(f, "_cache_size", None) for f in self._forms.values()]
+        return None if None in sizes else sum(n() for n in sizes)
+
+
 def _struct_key(tree: Any) -> Tuple:
     """Hashable (treedef, leaf shapes/dtypes) key for jit memoization."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
@@ -164,8 +192,8 @@ class ShardedDecodeMixin:
             else self._mesh_jit(fn, kind="extend_batch")
 
     # jaxlint: shapes(fused_step=2, fused_step_sel=1)
-    def _make_fused_step(self, opts=None, *,
-                         kind: str = "fused_step") -> Callable:
+    def _make_fused_step(self, opts=None, *, kind: str = "fused_step",
+                         donate: bool = False) -> Callable:
         """(params, feed, caches) -> (last_logits, caches, stats): the
         fused megabatch tick over the PERSISTENT batched cache tree.
         ``opts`` overrides ``self.opts`` for this build — the engine uses
@@ -185,9 +213,14 @@ class ShardedDecodeMixin:
         call (``stats["sampled"]``), making a whole tick exactly one
         device dispatch: a decode row's next token and a finishing
         prefill row's first token come out together. Length-0 rows stay
-        bit-identical via the ragged scan's per-leaf masked writes.
+        bit-identical via the ragged scan's row-masked writes.
         Under a mesh, rows shard over "data" exactly like the batched
-        extend (the [1, 2] key replicates)."""
+        extend (the [1, 2] key replicates).
+
+        Returns an :class:`InPlaceStep` whose ``in_place`` donates the
+        cache tree when ``donate``: the step then updates it in place and
+        the caller's input tree is deleted, so only a caller that reads
+        nothing of that tree after the call may ask for it."""
         temperature = self.temperature
         opts = self.opts if opts is None else opts
 
@@ -211,10 +244,16 @@ class ShardedDecodeMixin:
                 return last_logits, caches, {**st, "sampled": sampled,
                                              "kv_tokens_rows": kv_rows}
 
-        return jax.jit(fn) if self.mesh is None \
-            else self._mesh_jit(fn, kind=kind)
+        if self.mesh is None:
+            forms = {d: jax.jit(fn, donate_argnums=2 if d else ())
+                     for d in (False, True)}
+        else:
+            forms = {d: self._mesh_jit(fn, kind=kind, donate=d)
+                     for d in (False, True)}
+        return InPlaceStep(forms, donate)
 
-    def _mesh_jit(self, fn: Callable, *, kind: str) -> Callable:
+    def _mesh_jit(self, fn: Callable, *, kind: str,
+                  donate: bool = False) -> Callable:
         """Wrap ``fn(params, tokens, caches)`` with explicit in/out
         shardings, memoized per (tokens, caches) structure — the batched
         decode, the batch-1 prefill tail, and the ragged batched extend
@@ -222,10 +261,10 @@ class ShardedDecodeMixin:
         tree) share one engine but need different placements."""
 
         def call(params, tokens, caches):
-            key = (kind,) + _struct_key((tokens, caches))
+            key = (kind, donate) + _struct_key((tokens, caches))
             ent = self._fn_cache.get(key)
             if ent is None:
-                ent = self._build_mesh_jit(fn, tokens, caches)
+                ent = self._build_mesh_jit(fn, tokens, caches, donate)
                 self._fn_cache[key] = ent
             jfn, tok_sh, csh = ent
             # eager prefill / splice outputs may carry compiler-chosen
@@ -235,7 +274,8 @@ class ShardedDecodeMixin:
 
         return call
 
-    def _build_mesh_jit(self, fn, tokens, caches):  # jaxlint: shapes(mesh-jit=per-structure)
+    def _build_mesh_jit(self, fn, tokens, caches,  # jaxlint: shapes(mesh-jit=per-structure)
+                        donate: bool = False):
         mesh, cfg = self.mesh, self.cfg
         csh = self.cache_shardings_for(caches)
         # feed leaves with a batch-leading axis (tokens/lengths/device
@@ -258,7 +298,8 @@ class ShardedDecodeMixin:
                   rules.cache_shardings(caches_s, mesh, cfg),
                   jax.tree.map(row_or_repl, stats_s))
         jfn = jax.jit(fn, in_shardings=(self._param_sh, tok_sh, csh),
-                      out_shardings=out_sh)
+                      out_shardings=out_sh,
+                      donate_argnums=2 if donate else ())
         return jfn, tok_sh, csh
 
     # ------------------------------------------------------------------

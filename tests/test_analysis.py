@@ -259,6 +259,55 @@ def test_jl003_at_write_needs_mask():
     assert ok == []
 
 
+JL003_ROW_MASKED = """
+    import jax.numpy as jnp
+
+    def body(carry, xs):  # jaxlint: masked-scan-body
+        old, last = carry
+        tok, active = xs
+        logits, new, st = decode_step(params, cfg, tok, old, active=active)
+        last = jnp.where(active[:, None], logits, last)
+        return (new, last), st
+"""
+
+
+def test_jl003_row_mask_at_the_write_is_clean():
+    # the decode step given the row mask masks rows at each write it makes
+    assert findings_for(JL003_ROW_MASKED, select=["JL003"]) == []
+
+
+@pytest.mark.parametrize("call", [
+    "decode_step(params, cfg, tok, old)",
+    "decode_step(params, cfg, tok, old, active=None)",
+], ids=["no-mask", "mask-none"])
+def test_jl003_decode_step_without_row_mask_flagged(call):
+    fs = findings_for(
+        JL003_ROW_MASKED.replace(
+            "decode_step(params, cfg, tok, old, active=active)", call),
+        select=["JL003"])
+    assert codes(fs) == ["JL003"] and "'new'" in fs[0].message
+
+
+def test_jl003_unmasked_touched_slot_write_still_flagged():
+    # a stacked write given the row mask is masked; a scatter at the
+    # touched slot without a select, in the same body, still fails
+    fs = findings_for(
+        """
+        import jax.numpy as jnp
+
+        def body(carry, xs):  # jaxlint: masked-scan-body
+            cache, last = carry
+            k_new, g_new, layer, active = xs
+            cache = lazy_promote_and_write(cache, k_new, k_new, g_new,
+                                           tau=0.1, layer=layer,
+                                           active=active)
+            lk = cache.lk.at[layer, rows, :, cache.ptr[layer]].set(k_new)
+            return (cache, last), lk
+        """,
+        select=["JL003"])
+    assert codes(fs) == ["JL003"] and ".at[...]" in fs[0].message
+
+
 def test_jl003_suppression():
     fs = findings_for(
         JL003_CLEAN.replace(
@@ -550,8 +599,8 @@ SEEDED = [
      '            self.stats["fused_slot_rows"] += float(self.slots)\n'
      '            _cat = jnp.concatenate([jnp.zeros((1,)), jnp.zeros((1,))])'),
     ("JL003", "src/repro/models/inference.py",
-     "        merged = jax.tree_util.tree_map_with_path(keep, new, old)",
-     "        merged = new"),
+     "scan_unroll=scan_unroll, active=active)",
+     "scan_unroll=scan_unroll)"),
     ("JL004", "src/repro/serving/sharded.py",
      "                    sampled = sample(key[0], last_logits,\n"
      "                                     temperature=temperature)",

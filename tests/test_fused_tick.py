@@ -345,3 +345,107 @@ def test_compile_sentinel_over_selection_replay(served):
         counts = cs.check()
     assert counts["fused_step_sel"] == 1
     assert counts["fused_step"] <= 2
+
+
+# ==========================================================================
+# in-place cache update: the fused step donates its cache tree unless
+# something reads the input tree after the call (the paged mirror)
+# ==========================================================================
+def _leaves_deleted(tree):
+    return [leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(tree)]
+
+
+def test_mirror_off_fused_dispatch_donates(served):
+    """Without the mirror the fused call updates the tree in place: the
+    tree a dispatch was given is deleted once it returns, and every
+    fused step counts as donated. A mirror-on engine (its collect diffs
+    the input tree) never donates."""
+    cfg, params = served
+    for mirror in (False, True):
+        eng = make_backend("wgkv", params, cfg, slots=4, capacity=128,
+                           mirror_paged=mirror)
+        t = eng.start_prefill(list(range(2, 40)))
+        t.slot = 1
+        s1 = eng.step_batch([t], CHUNK)
+        given = s1.after
+        s2 = eng.step_batch([t], CHUNK)
+        assert all(_leaves_deleted(given)) is not mirror
+        assert not any(_leaves_deleted(eng.caches))
+        eng.collect(s1)
+        eng.collect(s2)
+        assert eng.stats["fused_steps"] == 2
+        assert eng.stats["fused_donated_steps"] == (0 if mirror else 2)
+        assert (s1.donated, s2.donated) == (not mirror, not mirror)
+
+
+@pytest.mark.parametrize("mode", ["fused", "ahead0", "ahead1"])
+def test_donating_streams_equal_mirror_on(served, mode):
+    """A donating (mirror-off) engine streams byte for byte what a
+    non-donating (mirror-on) engine streams at the same seed: driven by
+    hand with two fused steps in flight, and through the scheduler at
+    dispatch depths 0 and 1."""
+    cfg, params = served
+    prompts = [list(range(10, 58)), list(range(5, 60)),
+               list(range(20, 30)), list(range(7, 52))]
+
+    def by_hand(eng):
+        tasks = []
+        for slot, p in enumerate(prompts):
+            t = eng.start_prefill(p)
+            t.slot = slot
+            tasks.append(t)
+        out = {s: [] for s in range(len(prompts))}
+        inflight = []
+        while any(len(v) < 5 for v in out.values()):
+            step = eng.step_batch([t for t in tasks if not t.done], CHUNK)
+            inflight.append(step)
+            if len(inflight) == 2:
+                for s, tok in eng.collect(inflight.pop(0)).items():
+                    out[s].append(tok)
+        for s, tok in eng.collect(inflight.pop(0)).items():
+            out[s].append(tok)
+        return [v[:5] for v in out.values()], eng.stats
+
+    def scheduled(eng, depth):
+        orch = Orchestrator(eng, sched=SchedulerConfig(
+            chunk_tokens=CHUNK, dispatch_ahead=depth))
+        for p in prompts:
+            orch.submit(p, max_new=5)
+        orch.run()
+        return ([orch.tokens(r) for r in range(len(prompts))],
+                orch.telemetry.counters)
+
+    runs = {}
+    for mirror in (False, True):
+        eng = make_backend("wgkv", params, cfg, slots=4, capacity=128,
+                           mirror_paged=mirror, seed=7)
+        runs[mirror] = by_hand(eng) if mode == "fused" else \
+            scheduled(eng, int(mode[-1]))
+    (toks, c_off), (ref, c_on) = runs[False], runs[True]
+    assert toks == ref
+    assert all(len(t) == 5 for t in toks)
+    assert c_off["fused_steps"] > 0
+    assert c_off["fused_donated_steps"] == c_off["fused_steps"]
+    assert c_on["fused_donated_steps"] == 0
+
+
+def test_fused_step_temp_below_one_cache_tree(served):
+    """The compiled ``[slots, chunk]`` fused step needs less scratch
+    memory than one cache tree: each position writes only the slots it
+    touches, in place, and no copy of the tree is made per position."""
+    from repro.launch.specs import cache_tree_bytes
+    cfg, params = served
+    eng = make_backend("wgkv", params, cfg, slots=4, capacity=2048,
+                       mirror_paged=False)
+    caches = jax.eval_shape(
+        lambda: alloc_batched_caches(eng._build_empty_caches(), eng.slots))
+    b = eng.slots
+    feed = (jax.ShapeDtypeStruct((b, CHUNK), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.bool_),
+            jax.ShapeDtypeStruct((1, 2), jnp.uint32))
+    mem = eng._fused.lower(eng.params, feed, caches).compile() \
+        .memory_analysis()
+    tree = cache_tree_bytes(caches)
+    assert 0 < mem.temp_size_in_bytes < tree, (mem.temp_size_in_bytes, tree)

@@ -267,3 +267,35 @@ def test_async_dispatch_hits_match_sync(served):
     assert warm == cold and pc.hits == 2
     pc.clear()
     assert eng.pool.pages_in_use == 0
+
+
+def test_capture_under_dispatch_ahead_mirror_off_matches_mirror_on(served):
+    """With the mirror off the fused step donates its tree, so the next
+    dispatch deletes the tree a capture was registered against; the row
+    is held at dispatch instead, and the stored tree equals, leaf for
+    leaf, the one a mirror-on (non-donating) engine captures."""
+    cfg, eng_on = served
+    eng_off = make_backend("wgkv", eng_on.params, cfg, slots=2,
+                           capacity=192, mirror_paged=False)
+    prompts = _prompts(cfg, tails=(8, 8), seed=4)
+    entries = {}
+    for name, eng in (("off", eng_off), ("on", eng_on)):
+        pc = PrefixCache(quantum=CHUNK, free_fn=eng.release_prefix)
+        _, sess = _serve(eng, prompts, pc, dispatch_ahead=1)
+        (entries[name],) = pc._entries.values()
+        c = sess.telemetry.counters
+        assert c["fused_donated_steps"] == (
+            c["fused_steps"] if name == "off" else 0)
+        entries[name + "_pc"] = pc
+    off, on = entries["off"], entries["on"]
+    assert off.n_tokens == on.n_tokens == 48
+    assert off.kv_tokens == on.kv_tokens
+    flat_off = jax.tree_util.tree_flatten_with_path(off.caches)[0]
+    flat_on = jax.tree_util.tree_flatten_with_path(on.caches)[0]
+    assert len(flat_off) == len(flat_on)
+    for (path, a), (_, b) in zip(flat_off, flat_on):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
+    entries["on_pc"].clear()
+    entries["off_pc"].clear()
+    assert eng_on.pool.pages_in_use == 0

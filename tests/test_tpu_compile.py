@@ -126,3 +126,43 @@ def test_fused_step_full_width_compiles(one_chip, backend, s):
     want = expected_scopes(backend)
     found = scopes_in(compiled.as_text())
     assert want <= found, want - found
+
+
+@pytest.mark.parametrize("s", [CHUNK, 1], ids=["chunk", "decode"])
+def test_fused_step_write_kernel_compiles(one_chip, monkeypatch, s):
+    """On one chip the decode write is the Pallas kernel
+    (``kernels/cache_write.py``): the full-width fused step compiles with
+    it, every per-slot leaf it writes stays in HBM around the call (XLA
+    would otherwise stage the smaller stacked leaves in on-chip memory at
+    every layer), and the step still updates the tree in place."""
+    import re
+    from repro.core import dual_cache as DC
+    from repro.launch.specs import cache_tree_bytes
+    from repro.models.inference import DecodeOptions
+    monkeypatch.setattr(DC, "interpret_mode", lambda: False)
+    cfg = get_config("qwen3-0.6b")
+    params = jax.eval_shape(lambda k: T.init_model(k, cfg),
+                            jax.random.PRNGKey(0))
+    eng = make_backend("wgkv", params, cfg, slots=SLOTS, capacity=CAPACITY,
+                       mirror_paged=False,
+                       opts=DecodeOptions(write_kernel=True))
+    caches = jax.eval_shape(
+        lambda: alloc_batched_caches(eng._build_empty_caches(), SLOTS))
+    feed = (jax.ShapeDtypeStruct((SLOTS, s), jnp.int32),
+            jax.ShapeDtypeStruct((SLOTS,), jnp.int32),
+            jax.ShapeDtypeStruct((SLOTS,), jnp.int32),
+            jax.ShapeDtypeStruct((SLOTS,), jnp.bool_),
+            jax.ShapeDtypeStruct((1, 2), jnp.uint32))
+    compiled = eng._fused.lower(*_on(one_chip, (params, feed, caches))
+                                ).compile()
+    text = compiled.as_text()
+    assert _is_mosaic(compiled)
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "cache_write/pallas_call" in line]
+    assert len(calls) == 1, len(calls)
+    outs = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", calls[0].split(
+        " custom-call(")[0])
+    # lk lv gk gv pkmin pkmax lg lpos gpos, then the two counters
+    assert len(outs) == 11 and not any("S(1)" in o for o in outs[:9]), outs
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        cache_tree_bytes(caches)
